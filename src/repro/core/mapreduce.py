@@ -17,36 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.5 exports shard_map at top level
-    _new_shard_map = jax.shard_map
-    _old_shard_map = None
-except AttributeError:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map as _old_shard_map
-
-    _new_shard_map = None
-
-
-def shard_map(f, mesh, in_specs, out_specs, axis_names=None, check_vma=None):
-    """Version-portable ``shard_map`` (the repo's single entry point).
-
-    Accepts the jax >= 0.5 surface (``axis_names`` = manual axes,
-    ``check_vma``) and translates to the jax 0.4 experimental API
-    (``auto`` = complementary axis set, ``check_rep``) when needed.
-    """
-    if _new_shard_map is not None:
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        return _new_shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    kw = {}
-    if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - set(axis_names)
-    if check_vma is not None:
-        kw["check_rep"] = check_vma
-    return _old_shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
 _REDUCERS = {
     "sum": jax.lax.psum,
     "max": jax.lax.pmax,
@@ -93,7 +63,11 @@ def mapreduce(
         partial = job.map_fn(*args)
         return jax.tree.map(lambda x: reducer(x, axes), partial)
 
-    fn = shard_map(_mapper, mesh=mesh, in_specs=tuple(in_specs), out_specs=out_specs)
+    # check_vma=False: map functions call Pallas kernels, and pallas_call's
+    # out_shape carries no mesh-axis variance (vma) for the check to type;
+    # the job states its reduction itself (reduce_axes + out_specs)
+    fn = jax.shard_map(_mapper, mesh=mesh, in_specs=tuple(in_specs),
+                       out_specs=out_specs, check_vma=False)
     return jax.jit(fn) if jit else fn
 
 

@@ -40,6 +40,7 @@ Fault tolerance (DESIGN.md §11):
 
 from __future__ import annotations
 
+import functools
 import math
 import time
 from typing import TYPE_CHECKING, Callable
@@ -65,6 +66,7 @@ if TYPE_CHECKING:  # import-time would cycle: data.store -> core -> streaming
     from repro.data.store import TransactionStore
 
 
+@functools.lru_cache(maxsize=32)
 def make_accum_count_step(mesh, cfg: ap.AprioriConfig) -> Callable:
     """The combiner: jit'd ``(t_chunk, c, lengths, acc) -> acc + counts``.
 
@@ -72,6 +74,8 @@ def make_accum_count_step(mesh, cfg: ap.AprioriConfig) -> Callable:
     and the mesh Map/Reduce shape are all inherited unchanged) and folds the
     chunk's counts into a device-resident int32 accumulator — partial
     aggregation happens where the data is, exactly like a Hadoop combiner.
+    Cached per (mesh, config): a later mine of the same job reuses the
+    compiled step instead of compiling every candidate bucket again.
     """
     count_step = ap.make_count_step(mesh, cfg)
 

@@ -123,15 +123,16 @@ def support_count_packed(
     impl: str = "auto",
     block_n: int = 256,
     block_k: int = 256,
-    block_w: int = 8,
     mode: str = "and_cmp",
 ):
     """Support counts over packed uint32 bitset operands (exact int32).
 
     t_packed: (N, W) uint32, c_packed: (K, W) uint32, lengths: (K,) int32
     with |c| = -1 marking padded candidate rows. Accepts arbitrary (N, W, K);
-    pads rows/words to kernel block multiples internally (zero words / zero
-    rows / -1 lengths — all inert, DESIGN.md §3).
+    pads rows/candidates to kernel block multiples internally (zero rows /
+    -1 lengths — inert, DESIGN.md §3). The word axis is tiled in 128-lane
+    slabs when W is a multiple of 128 and taken whole otherwise: Mosaic
+    accepts no other minor block.
     impl: auto | jnp | pallas | pallas_interpret
     """
     impl = resolve_impl(impl)
@@ -144,10 +145,10 @@ def support_count_packed(
 
     block_n = min(block_n, _round_up(n, 8))
     block_k = min(block_k, _round_up(k, 128))
-    block_w = min(block_w, w)
-    np_, kp, wp = _round_up(n, block_n), _round_up(k, block_k), _round_up(w, block_w)
-    t_p = jnp.pad(t_packed, ((0, np_ - n), (0, wp - w)))
-    c_p = jnp.pad(c_packed, ((0, kp - k), (0, wp - w)))
+    block_w = w if w % 128 else 128
+    np_, kp = _round_up(n, block_n), _round_up(k, block_k)
+    t_p = jnp.pad(t_packed, ((0, np_ - n), (0, 0)))
+    c_p = jnp.pad(c_packed, ((0, kp - k), (0, 0)))
     len_p = jnp.pad(lengths.astype(jnp.int32), (0, kp - k), constant_values=-1)
     counts = support_count_packed_pallas(
         t_p,

@@ -76,4 +76,5 @@ def rule_match_ref(b_packed, a_packed, lengths, c_packed, scores):
     matched = contains & (lengths.astype(jnp.int32) >= 0)[None, :]
     weights = matched.astype(jnp.float32) * scores.astype(jnp.float32)[None, :]
     cons_dense = unpack_bits_ref(c_packed, 32 * c_packed.shape[1])  # (R, 32·W)
-    return weights @ cons_dense
+    # HIGHEST: XLA's default f32 matmul precision on TPU rounds the scores
+    return jnp.matmul(weights, cons_dense, precision=jax.lax.Precision.HIGHEST)

@@ -62,14 +62,18 @@ def _kernel(b_ref, a_ref, len_ref, c_ref, score_ref, out_ref, *, num_words):
     weights = matched.astype(jnp.float32) * score_ref[...]  # (bn, bk)
 
     # --- consequent fan-out: unpack bitsets in-register, one MXU matmul ---
+    # (Mosaic has no uint32 -> f32 cast; the bit is 0/1, so go through int32)
     c = c_ref[...]  # (bk, W) uint32
     shifts = jax.lax.broadcasted_iota(jnp.uint32, (1, 32), 1)
     cols = [
-        ((c[:, w : w + 1] >> shifts) & jnp.uint32(1)).astype(jnp.float32)
+        ((c[:, w : w + 1] >> shifts) & jnp.uint32(1)).astype(jnp.int32).astype(jnp.float32)
         for w in range(num_words)
     ]
     cons_dense = jnp.concatenate(cols, axis=1)  # (bk, 32·W) — little-endian items
-    contrib = jnp.dot(weights, cons_dense, preferred_element_type=jnp.float32)
+    # HIGHEST: the weights are f32 scores, and a reduced-precision pass
+    # would round them on the MXU
+    contrib = jnp.dot(weights, cons_dense, preferred_element_type=jnp.float32,
+                      precision=jax.lax.Precision.HIGHEST)
 
     @pl.when(r == 0)
     def _init():

@@ -39,8 +39,10 @@ under ``and_cmp``, which never inspects the length's magnitude.
 
 The per-tile word loop is a *static* Python unroll over ``block_w`` lane
 slices — no dynamic lane indexing, which keeps the Mosaic lowering to plain
-VPU ops.  VMEM per step = bn·bw·4 + bk·bw·4 + bn·bk·4; defaults
-(256, 256, 8) give ≈ 0.27 MB, far under budget, leaving room for double
+VPU ops.  Mosaic takes a minor block that is the whole word axis or a
+multiple of 128 lanes, so ``kernels.ops`` passes ``block_w = W`` unless W is
+a multiple of 128.  VMEM per step = bn·bw·4 + bk·bw·4 + bn·bk·4; at
+(256, 256, 32) that is ≈ 0.33 MB, far under budget, leaving room for double
 buffering.
 """
 
@@ -110,13 +112,13 @@ def support_count_packed_pallas(
     *,
     block_n: int = 256,
     block_k: int = 256,
-    block_w: int = 8,
+    block_w: int = 128,
     mode: str = "and_cmp",
     interpret: bool = False,
 ) -> jax.Array:
     """Counts for pre-padded packed operands: N % block_n == K % block_k ==
-    W % block_w == 0 (use kernels.ops.support_count_packed for the
-    padding/packing wrapper).
+    W % block_w == 0, with block_w == W or a multiple of 128 on the chip
+    (use kernels.ops.support_count_packed for the padding/packing wrapper).
     """
     n, w = t_packed.shape
     k, w2 = c_packed.shape

@@ -4,19 +4,12 @@ importing this module never touches jax device state."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_auto_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where supported.
-
-    jax >= 0.5 takes ``axis_types``; jax 0.4 has neither ``AxisType`` nor the
-    kwarg (all axes behave as Auto there). The single version-portable mesh
-    entry point for launch scripts, tests and benches.
-    """
-    try:
-        from jax.sharding import AxisType
-    except ImportError:  # jax < 0.5
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with Auto axis types: the mesh entry point for
+    launch scripts, tests and benches."""
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 

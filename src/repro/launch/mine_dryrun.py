@@ -27,7 +27,7 @@ def run(variant: str, n=1 << 20, items=2048, k_cands=1 << 16):
     from repro.core.apriori import AprioriConfig, make_count_step
     from repro.launch import hlo_analysis
     from repro.launch.mesh import make_production_mesh
-    from repro.launch.roofline import roofline_terms
+    from repro.launch.roofline import V5E, roofline_terms
 
     mesh = make_production_mesh()
     if variant == "paper_1d":
@@ -53,7 +53,8 @@ def run(variant: str, n=1 << 20, items=2048, k_cands=1 << 16):
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
     hlo = hlo_analysis.summarize(compiled.as_text())
-    rl = roofline_terms(hlo["flops"], hlo["hbm_bytes"], hlo["collective_bytes"])
+    # the production mesh is a v5e pod slice, described on host devices
+    rl = roofline_terms(hlo["flops"], hlo["hbm_bytes"], hlo["collective_bytes"], V5E)
     model_flops = 2.0 * n * items * k_cands / 256
     return {
         "variant": variant,

@@ -1,9 +1,8 @@
-"""Three-term roofline from the compiled dry-run artifact.
+"""Three-term roofline from a compiled step's static HLO walk.
 
-Hardware constants (TPU v5e target):
-  peak compute  197 TFLOP/s bf16 per chip
-  HBM bandwidth 819 GB/s per chip
-  ICI           ~50 GB/s per link
+Peaks are per chip and keyed by ``jax.Device.device_kind``; a device that is
+not in :data:`PEAKS` gets no roofline (``None``, reported "not measured"),
+never another chip's numbers.
 
   compute term    = FLOPs_per_device            / peak_FLOPs
   memory term     = HBM_bytes_per_device        / HBM_bw
@@ -23,11 +22,13 @@ dispatch overhead.
 from __future__ import annotations
 
 import dataclasses
-import math
 
-PEAK_FLOPS = 197e12       # bf16 / chip
-HBM_BW = 819e9            # B/s / chip
-ICI_BW = 50e9             # B/s / link
+# Google Cloud, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s of
+# chip-to-chip interconnect over 4 links (50 GB/s each)
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+V5E = "TPU v5 lite"
 
 
 @dataclasses.dataclass
@@ -55,9 +56,14 @@ class Roofline:
         return self.compute_s / max(self.bound_s, 1e-30)
 
 
-def roofline_terms(flops_per_dev: float, hbm_bytes_per_dev: float, coll_bytes_per_dev: float) -> Roofline:
+def roofline_terms(flops_per_dev: float, hbm_bytes_per_dev: float,
+                   coll_bytes_per_dev: float, device_kind: str) -> Roofline | None:
+    """The three terms on ``device_kind``'s peaks; None for an unknown device."""
+    peaks = PEAKS.get(device_kind)
+    if peaks is None:
+        return None
     return Roofline(
-        compute_s=flops_per_dev / PEAK_FLOPS,
-        memory_s=hbm_bytes_per_dev / HBM_BW,
-        collective_s=coll_bytes_per_dev / ICI_BW,
+        compute_s=flops_per_dev / peaks["flops"],
+        memory_s=hbm_bytes_per_dev / peaks["hbm_bw"],
+        collective_s=coll_bytes_per_dev / peaks["ici_bw"],
     )

@@ -161,6 +161,7 @@ def main():
     from repro.data.store import append_chunks, ingest_quest, open_store
     from repro.data.synthetic import QuestConfig, gen_transactions_chunked
     from repro.distributed import FaultConfig
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.serving import (
         AdmissionRejected,
         Gateway,
@@ -168,6 +169,8 @@ def main():
         Router,
         compile_rulebook,
     )
+
+    enable_compile_cache()
 
     # ---- 1. load (or ingest) the on-disk store ----
     qcfg = QuestConfig(num_transactions=args.transactions, num_items=args.items,

@@ -320,6 +320,11 @@ class Gateway:
         return self._generation.generation
 
     @property
+    def devices(self) -> set:
+        """Devices holding the serving generation's rulebook columns."""
+        return self._generation.rulebook.ante_packed.devices()
+
+    @property
     def queue_depth(self) -> int:
         """Requests currently queued in the batcher."""
         return self._batcher.depth

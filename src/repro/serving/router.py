@@ -55,6 +55,10 @@ import threading
 import time
 from concurrent.futures import Future
 
+import jax
+import numpy as np
+from jax.sharding import Mesh
+
 from repro.distributed.fault_tolerance import FaultConfig, InjectedFailure, retry_delay
 from repro.distributed.supervisor import ReplicaSetSupervisor
 from repro.obs.registry import Histogram
@@ -289,14 +293,17 @@ class Router:
         # availability SLO tightens admission instead of letting queues fill
         self._brownout_level = 0
 
-        # N fully independent gateways: own batcher, own cache, own device
-        # placement. The jit cache is shared underneath (same shapes, same
-        # cached match step), so replica warmup compiles mostly once.
-        # replicas share the router's tracer but never START a trace
+        # N fully independent gateways: own batcher, own cache, own device.
+        # Replica i serves from device i (mod the device count) through a
+        # one-device mesh, so its rulebook columns and batches are placed
+        # there. replicas share the router's tracer but never START a trace
         # themselves (trace_root=False): one request = one trace, sampled
         # once at the router, continued through whichever replicas serve it
+        devices = jax.devices()
         self._replicas = [
             Replica(rid, Gateway(rulebook, tracer=tracer, trace_root=False,
+                                 mesh=Mesh(np.asarray(devices[rid % len(devices)]).reshape(1, 1),
+                                           ("data", "model")),
                                  **gateway_kwargs))
             for rid in range(num_replicas)
         ]

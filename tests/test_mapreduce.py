@@ -90,3 +90,38 @@ def test_shard_count_invariance_multidevice():
     )
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert "INVARIANCE_OK" in proc.stdout
+
+
+_PALLAS_MESH_SCRIPT = textwrap.dedent(
+    """
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax
+    from repro.core.apriori import AprioriConfig, mine
+    from repro.data.synthetic import QuestConfig, gen_transactions
+    from repro.launch.mesh import make_auto_mesh
+
+    T = gen_transactions(QuestConfig(num_transactions=400, num_items=70, avg_len=6,
+                                     num_patterns=8, seed=3))
+    mesh = make_auto_mesh((2, 2), ("data", "model"))
+    for rep in ("dense", "packed"):
+        single = mine(T, AprioriConfig(min_support=0.05, max_k=3, count_impl="jnp",
+                                       representation=rep))
+        dist = mine(T, AprioriConfig(min_support=0.05, max_k=3,
+                                     count_impl="pallas_interpret", representation=rep,
+                                     data_axes=("data",), model_axis="model"), mesh=mesh)
+        assert dist.as_dict() == single.as_dict(), rep
+        print("PALLAS_MESH_OK", rep, single.total_frequent)
+    """
+)
+
+
+def test_pallas_kernels_inside_mesh_mapreduce():
+    """A Pallas count kernel inside the 2x2 shard_map job (4 host devices)
+    mines exactly what one device does, in both representations."""
+    proc = subprocess.run(
+        [sys.executable, "-c", _PALLAS_MESH_SCRIPT],
+        capture_output=True, text=True, timeout=600, env=subprocess_env(), cwd=REPO_ROOT,
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert proc.stdout.count("PALLAS_MESH_OK") == 2
