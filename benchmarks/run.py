@@ -854,8 +854,8 @@ def _obs_p99_breakdown_row(quick):
     total_ms = p99.duration_s() * 1e3
     row("obs_p99_breakdown", total_ms * 1e3,
         f"queue_ms={p99.attrs['queue_ms']:.2f};"
-        f"batch_ms={p99.attrs['batch_ms']:.3f};"
-        f"device_ms={p99.attrs['device_ms']:.2f};"
+        f"launch_ms={p99.attrs['launch_ms']:.3f};"
+        f"fetch_ms={p99.attrs['fetch_ms']:.2f};"
         f"total_ms={total_ms:.2f};sampled={len(reqs)}")
 
 

@@ -27,7 +27,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import time
 from typing import Callable
 
 import jax
@@ -40,6 +39,7 @@ from repro.core import candidates as cand_mod
 from repro.core import itemsets as enc
 from repro.core.mapreduce import MapReduceJob, mapreduce, pad_rows_to_shards
 from repro.kernels import ops as kops
+from repro.obs.mining import phase
 
 
 @dataclasses.dataclass(frozen=True)
@@ -119,21 +119,24 @@ def make_count_step(
     with T sharded over data_axes -> counts (Kp,) int32, replicated over the
     data axes, sharded over model_axis. The sharded path is identical for
     both representations — P(data_axes, None) over rows, whatever the row
-    payload is (DESIGN.md §2).
+    payload is (DESIGN.md §2). Its ops carry the name scope
+    ``repro.count_step``, whatever implements the count.
     """
     if cfg.representation == "packed":
 
         def local_count(t, c, ln):
-            return kops.support_count_packed(
-                t, c, ln, impl=cfg.count_impl, mode=cfg.packed_mode
-            )
+            with jax.named_scope("repro.count_step"):
+                return kops.support_count_packed(
+                    t, c, ln, impl=cfg.count_impl, mode=cfg.packed_mode
+                )
 
     elif cfg.representation == "dense":
 
         def local_count(t, c, ln):
-            return kops.support_count(
-                t, c, ln, impl=cfg.count_impl, operand_dtype=cfg.operand_dtype
-            )
+            with jax.named_scope("repro.count_step"):
+                return kops.support_count(
+                    t, c, ln, impl=cfg.count_impl, operand_dtype=cfg.operand_dtype
+                )
 
     else:
         raise ValueError(f"representation must be dense|packed, got {cfg.representation!r}")
@@ -263,11 +266,10 @@ def run_level_loop(
 
     if start_k <= 1:
         # level 1: supports of singletons — the same count path (uniform Map/Reduce)
-        t_gen0 = time.perf_counter()
-        singles = enc.singleton_itemsets(num_items)
+        with phase(obs, "level", "candidate_gen"):
+            singles = enc.singleton_itemsets(num_items)
         if obs is not None:
             obs.on_level_start(1, singles.shape[0])
-            obs.add_phase("candidate_gen", t_gen0, time.perf_counter())
         sup1 = count_fn(singles, 1)
         keep = sup1 >= min_count
         levels[1] = (singles[keep], sup1[keep])
@@ -281,19 +283,18 @@ def run_level_loop(
         prev_sets = levels.get(k - 1, (np.zeros((0, k - 1), np.int32),))[0]
         if prev_sets.shape[0] < k:   # cannot form a k-itemset
             break
-        t_gen0 = time.perf_counter()
-        if cfg.use_naive_paper_map:
-            # paper §3.3: enumerate every k-subset of the (frequent) item universe
-            freq_items = levels[1][0].ravel()
-            combos = cand_mod.all_k_subsets_of_universe(freq_items.size, k)
-            cands = freq_items[combos]
-        else:
-            cands = cand_mod.generate_candidates(prev_sets)
+        with phase(obs, "level", "candidate_gen"):
+            if cfg.use_naive_paper_map:
+                # paper §3.3: enumerate every k-subset of the (frequent) item universe
+                freq_items = levels[1][0].ravel()
+                combos = cand_mod.all_k_subsets_of_universe(freq_items.size, k)
+                cands = freq_items[combos]
+            else:
+                cands = cand_mod.generate_candidates(prev_sets)
         if cands.shape[0] == 0:
             break
         if obs is not None:
             obs.on_level_start(k, cands.shape[0])
-            obs.add_phase("candidate_gen", t_gen0, time.perf_counter())
         sup = count_fn(cands, k)
         keep = sup >= min_count
         if obs is not None:

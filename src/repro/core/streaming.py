@@ -51,6 +51,7 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core import apriori as ap
+from repro.core import itemsets as enc
 from repro.core import son as son_mod
 from repro.data.pipeline import ShardedBatchIterator, batch_spec
 from repro.distributed.checkpoint import (
@@ -61,6 +62,7 @@ from repro.distributed.checkpoint import (
     store_fingerprint,
 )
 from repro.distributed.fault_tolerance import FaultConfig, run_partitions
+from repro.obs.mining import phase
 
 if TYPE_CHECKING:  # import-time would cycle: data.store -> core -> streaming
     from repro.data.store import TransactionStore
@@ -103,6 +105,37 @@ def _effective_chunk_rows(chunk_rows: int, cfg: ap.AprioriConfig, mesh) -> int:
     return ((chunk_rows + shards - 1) // shards) * shards
 
 
+def _chunks(store, chunk_rows: int, cfg: ap.AprioriConfig, obs=None, **where):
+    """The store's zero-padded chunks in the count step's representation
+    (``where``: ``start_chunk`` / ``shards`` of ``iter_chunks``).
+
+    The store yields packed words; the dense step's chunks are unpacked
+    here, as a step of its own, so that ``repro.stream.chunk_read`` and
+    ``repro.stream.chunk_unpack`` time the two apart on the thread that
+    draws the chunks."""
+    packed = store.iter_chunks(chunk_rows, pad=True, **where)
+    return _unpacked_chunks(packed, store.num_items, cfg.representation, obs)
+
+
+def _unpacked_chunks(packed, num_items: int, representation: str, obs):
+    while True:
+        with phase(obs, "stream", "chunk_read"):
+            nxt = next(packed, None)
+        if nxt is None:
+            return
+        if representation == "packed":
+            yield nxt[0]
+        else:
+            with phase(obs, "stream", "chunk_unpack"):
+                chunk = enc.unpack_bits(nxt[0], num_items)
+            yield chunk
+
+
+def _place_span(obs):
+    """The producer's span around each chunk's host→device placement."""
+    return functools.partial(phase, obs, "stream", "chunk_place")
+
+
 def _count_pass_chunks(
     accum_step,
     chunks,
@@ -129,14 +162,16 @@ def _count_pass_chunks(
 
     ``obs`` (an :class:`repro.obs.MiningObs`) attributes the pass's time:
     ``prefetch_stall`` is the fold blocking on the chunk iterator,
-    ``count_kernel`` the (async) dispatch of the accumulate step,
+    ``count_dispatch`` the (async) dispatch of the accumulate step,
     ``host_sync`` the final device→host transfer that also drains the
-    device queue, ``checkpoint_write`` the mid-pass saves.  The obs-off
-    path is the original untouched loop.
+    device queue, ``checkpoint_write`` the mid-pass saves; the producer
+    thread times ``chunk_place``.  The obs-off path is the original
+    untouched loop.
     """
     acc = _init_acc(kp, cfg, mesh, init=init_acc)
     done = chunks_done
-    it = ShardedBatchIterator(chunks, mesh, batch_spec(cfg.data_axes), prefetch=prefetch)
+    it = ShardedBatchIterator(chunks, mesh, batch_spec(cfg.data_axes), prefetch=prefetch,
+                              place_span=_place_span(obs))
     try:
         if obs is None:
             for t_chunk in it:
@@ -152,11 +187,9 @@ def _count_pass_chunks(
                     t_chunk = next(src)
                 except StopIteration:
                     break
-                t1 = time.perf_counter()
-                acc = accum_step(t_chunk, c_dev, len_dev, acc)
-                t2 = time.perf_counter()
-                obs.add_phase("prefetch_stall", t0, t1)
-                obs.add_phase("count_kernel", t1, t2)
+                obs.add_phase("prefetch_stall", t0, time.perf_counter())
+                with obs.span("stream", "count_dispatch"):
+                    acc = accum_step(t_chunk, c_dev, len_dev, acc)
                 obs.on_chunk(int(t_chunk.shape[0]))
                 done += 1
                 if save_fn is not None and save_every > 0 and done % save_every == 0:
@@ -165,12 +198,8 @@ def _count_pass_chunks(
                     obs.add_phase("checkpoint_write", t3, time.perf_counter())
     finally:
         it.close()
-    if obs is None:
+    with phase(obs, "level", "host_sync"):
         return np.asarray(acc)   # the final host sync of this candidate pass
-    t0 = time.perf_counter()
-    out = np.asarray(acc)
-    obs.add_phase("host_sync", t0, time.perf_counter())
-    return out
 
 
 def count_supports_streamed(
@@ -243,7 +272,8 @@ def _count_level_streamed(
         kp = ap._pad_bucket(chunk_c.shape[0], quantum)
         if obs is not None:
             obs.observe_max_candidate_bucket(kp)
-        c_dev, len_dev = ap._place_candidates(chunk_c, kp, num_items, cfg, mesh)
+        with phase(obs, "level", "candidate_place"):
+            c_dev, len_dev = ap._place_candidates(chunk_c, kp, num_items, cfg, mesh)
         init_acc, start_chunk = None, 0
         if resume_acc is not None:   # first pass after a mid-level resume only
             if resume_acc.shape[0] != kp:
@@ -253,15 +283,7 @@ def _count_level_streamed(
                 )
             init_acc, start_chunk = resume_acc, resume_chunks
             resume_acc = None
-        chunks = (
-            chunk
-            for chunk, _ in store.iter_chunks(
-                chunk_rows,
-                representation=cfg.representation,
-                pad=True,
-                start_chunk=start_chunk,
-            )
-        )
+        chunks = _chunks(store, chunk_rows, cfg, obs, start_chunk=start_chunk)
         if save_cb is not None and save_every > 0:
             def save_fn(acc_np, done, _start=start):
                 save_cb(counts, _start, acc_np, done)
@@ -440,16 +462,13 @@ def count_union_streamed(
             kp = ap._pad_bucket(chunk_c.shape[0], quantum)
             if obs is not None:
                 obs.observe_max_candidate_bucket(kp)
-            c_dev, len_dev = ap._place_candidates(chunk_c, kp, num_items, cfg, mesh)
+            with phase(obs, "level", "candidate_place"):
+                c_dev, len_dev = ap._place_candidates(chunk_c, kp, num_items, cfg, mesh)
             units.append([k, start, chunk_c.shape[0], c_dev, len_dev, _init_acc(kp, cfg, mesh)])
     if units:
-        chunks = (
-            chunk
-            for chunk, _ in store.iter_chunks(
-                chunk_rows, representation=cfg.representation, pad=True, shards=shards
-            )
-        )
-        it = ShardedBatchIterator(chunks, mesh, batch_spec(cfg.data_axes), prefetch=prefetch)
+        chunks = _chunks(store, chunk_rows, cfg, obs, shards=shards)
+        it = ShardedBatchIterator(chunks, mesh, batch_spec(cfg.data_axes), prefetch=prefetch,
+                                  place_span=_place_span(obs))
         try:
             if obs is None:
                 for t_chunk in it:
@@ -463,26 +482,22 @@ def count_union_streamed(
                         t_chunk = next(src)
                     except StopIteration:
                         break
-                    t1 = time.perf_counter()
-                    for u in units:
-                        u[5] = accum_step(t_chunk, u[3], u[4], u[5])
-                    t2 = time.perf_counter()
-                    obs.add_phase("prefetch_stall", t0, t1)
-                    obs.add_phase("count_kernel", t1, t2)
+                    obs.add_phase("prefetch_stall", t0, time.perf_counter())
+                    with obs.span("stream", "count_dispatch"):
+                        for u in units:
+                            u[5] = accum_step(t_chunk, u[3], u[4], u[5])
                     obs.on_chunk(int(t_chunk.shape[0]))
         finally:
             it.close()
 
-    t_sync0 = time.perf_counter()
     counts = {}
-    for k, cands in per_level.items():
-        sup = np.zeros(cands.shape[0], dtype=np.int64)
-        for uk, start, rows, _, _, acc in units:
-            if uk == k:
-                sup[start : start + rows] = np.asarray(acc)[:rows]
-        counts[k] = sup
-    if obs is not None:
-        obs.add_phase("host_sync", t_sync0, time.perf_counter())
+    with phase(obs, "level", "host_sync"):
+        for k, cands in per_level.items():
+            sup = np.zeros(cands.shape[0], dtype=np.int64)
+            for uk, start, rows, _, _, acc in units:
+                if uk == k:
+                    sup[start : start + rows] = np.asarray(acc)[:rows]
+            counts[k] = sup
     return counts
 
 

@@ -11,6 +11,7 @@ from __future__ import annotations
 import queue
 import threading
 import time
+from contextlib import nullcontext
 
 import jax
 import numpy as np
@@ -28,11 +29,15 @@ class ShardedBatchIterator:
     and ``close()`` drains the queue so a mid-put worker unblocks, then
     joins the thread. Iteration after ``close()`` raises StopIteration.
     Context-managed; exhausting the iterator also joins the worker.
+
+    ``place_span``: a factory of the context that each placement runs in,
+    on the worker thread (the caller's span; none by default).
     """
 
-    def __init__(self, gen, mesh, spec_fn, prefetch: int = 2):
+    def __init__(self, gen, mesh, spec_fn, prefetch: int = 2, place_span=nullcontext):
         self._gen = gen
         self._mesh = mesh
+        self._place_span = place_span
         self._spec_fn = spec_fn  # leaf_path-free: array -> PartitionSpec
         self._q: queue.Queue = queue.Queue(maxsize=prefetch)
         self._stop = threading.Event()
@@ -62,7 +67,9 @@ class ShardedBatchIterator:
             for batch in self._gen:
                 if self._stop.is_set():
                     return
-                if not self._put(self._place(batch)):
+                with self._place_span():
+                    placed = self._place(batch)
+                if not self._put(placed):
                     return
         except BaseException as e:  # surface generator/placement failures to
             self._err = e           # the consumer — NOT a clean end-of-stream

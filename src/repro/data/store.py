@@ -21,7 +21,7 @@ one shard of rows):
                                   so huge synthetic DBs never materialize
 
 Read path: :meth:`TransactionStore.iter_chunks` yields fixed-size row
-chunks (packed uint32 or unpacked dense int8) assembled across shard
+chunks of packed uint32 words assembled across shard
 boundaries; ``pad=True`` zero-pads the final chunk to the full chunk size —
 zero rows are inert for support counting in both representations
 (DESIGN.md §3), which is what lets the streaming driver jit one chunk shape.
@@ -185,15 +185,14 @@ class TransactionStore:
     def iter_chunks(
         self,
         chunk_rows: int,
-        representation: str = "packed",
         pad: bool = False,
         start_chunk: int = 0,
         shards: tuple | None = None,
     ):
         """Yield ``(chunk, valid_rows)`` covering all n rows in order.
 
-        chunk: (chunk_rows or fewer, words) uint32 when ``representation ==
-        "packed"``, (rows, num_items) int8 when ``"dense"``. Chunks are
+        chunk: (chunk_rows or fewer, words) uint32 packed rows (the miner
+        unpacks them for its dense step, ``core.streaming``). Chunks are
         assembled across shard boundaries, copying only the sliced rows out
         of the mmap. With ``pad=True`` every chunk has exactly
         ``chunk_rows`` rows, the tail zero-filled (inert, DESIGN.md §3).
@@ -214,8 +213,6 @@ class TransactionStore:
             raise ValueError("chunk_rows must be >= 1")
         if start_chunk < 0:
             raise ValueError("start_chunk must be >= 0")
-        if representation not in ("packed", "dense"):
-            raise ValueError(f"representation must be packed|dense, got {representation!r}")
         s0, s1 = (0, self.num_partitions) if shards is None else shards
         if not (0 <= s0 <= s1 <= self.num_partitions):
             raise ValueError(
@@ -239,19 +236,17 @@ class TransactionStore:
                 have += take
                 pos += take
                 if have == chunk_rows:
-                    yield self._emit(parts, have, chunk_rows, representation, pad)
+                    yield self._emit(parts, have, chunk_rows, pad)
                     parts, have = [], 0
         if have:
-            yield self._emit(parts, have, chunk_rows, representation, pad)
+            yield self._emit(parts, have, chunk_rows, pad)
 
-    def _emit(self, parts, have, chunk_rows, representation, pad):
+    def _emit(self, parts, have, chunk_rows, pad):
         packed = parts[0] if len(parts) == 1 else np.concatenate(parts, axis=0)
         if pad and have < chunk_rows:
             packed = np.concatenate(
                 [packed, np.zeros((chunk_rows - have, packed.shape[1]), np.uint32)]
             )
-        if representation == "dense":
-            return enc.unpack_bits(packed, self.num_items), have
         return packed, have
 
     def read_dense(self) -> np.ndarray:

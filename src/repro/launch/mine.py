@@ -47,51 +47,6 @@ import sys
 import time
 
 
-def static_count_cost(cfg, mesh, rows: int, num_items: int, k_cands: int) -> dict:
-    """Static roofline of ONE streamed count dispatch at the mined shapes.
-
-    Lowers the jnp count step (the dense reference decomposition — a shape-
-    faithful proxy for whatever impl actually ran) at (rows x num_items)
-    transactions against the LARGEST candidate bucket the mine dispatched,
-    and walks the compiled HLO (launch.hlo_analysis). Paired with the
-    measured ``count_kernel`` phase seconds this turns padding + dispatch
-    overhead into a reported ratio. The roofline uses the peaks of the
-    device it ran on; a device without peaks (the CPU) gets none.
-    """
-    import dataclasses
-
-    import jax
-    import jax.numpy as jnp
-
-    from repro.core.apriori import make_count_step
-    from repro.launch import hlo_analysis
-    from repro.launch.roofline import roofline_terms
-
-    jcfg = dataclasses.replace(cfg, count_impl="jnp", representation="dense")
-    step = make_count_step(mesh, jcfg)
-    t_sds = jax.ShapeDtypeStruct((rows, num_items), jnp.int8)
-    c_sds = jax.ShapeDtypeStruct((k_cands, num_items), jnp.int8)
-    l_sds = jax.ShapeDtypeStruct((k_cands,), jnp.int32)
-    fn = step.__wrapped__ if hasattr(step, "__wrapped__") else step
-    compiled = jax.jit(fn).lower(t_sds, c_sds, l_sds).compile()
-    hlo = hlo_analysis.summarize(compiled.as_text())
-    rl = roofline_terms(hlo["flops"], hlo["hbm_bytes"], hlo["collective_bytes"],
-                        jax.devices()[0].device_kind)
-    # the miner's useful-FLOPs model: K containment tests per row, each a
-    # words-per-row AND+popcount pass over packed uint32 bitsets
-    useful_flops = 2.0 * rows * num_items * k_cands / 256
-    return {
-        "rows_per_dispatch": rows,
-        "candidate_rows": k_cands,
-        "flops_per_dispatch": hlo["flops"],
-        "hbm_bytes_per_dispatch": hlo["hbm_bytes"],
-        "roofline_s_per_dispatch": None if rl is None else rl.bound_s,
-        "roofline_dominant": "not measured" if rl is None else rl.dominant,
-        "useful_flops_per_dispatch": useful_flops,
-        "useful_flops_ratio": useful_flops / max(hlo["flops"], 1.0),
-    }
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--transactions", type=int, default=20_000)
@@ -154,7 +109,7 @@ def main():
                          "the mining phase spans (load in ui.perfetto.dev)")
     ap.add_argument("--metrics-out", default="", metavar="PATH",
                     help="streamed mining: write the Hadoop-style job counters "
-                         "plus the static roofline cost of the count step as JSON")
+                         "as JSON")
     args = ap.parse_args()
 
     if args.host_devices and "XLA_FLAGS" not in os.environ:
@@ -312,21 +267,6 @@ def main():
         if args.metrics_out:
             counters = obs.counters()
             out = {"seconds": dt, "counters": counters}
-            k_cands = int(counters.get("mine_max_candidate_bucket", 0))
-            measured = counters.get('mine_phase_seconds{phase="count_kernel"}', 0.0)
-            dispatches = int(counters.get("mine_chunks_streamed", 0))
-            if k_cands > 0:
-                static = static_count_cost(
-                    cfg, mesh, min(args.stream_chunk_rows, store.num_transactions),
-                    store.num_items, k_cands)
-                static["count_dispatches"] = dispatches
-                static["measured_count_kernel_s"] = measured
-                if static["roofline_s_per_dispatch"] is None:
-                    static["measured_vs_roofline"] = "not measured"
-                else:
-                    ideal = static["roofline_s_per_dispatch"] * max(dispatches, 1)
-                    static["measured_vs_roofline"] = measured / max(ideal, 1e-12)
-                out["static_cost"] = static
             with open(args.metrics_out, "w") as f:
                 json.dump(out, f, indent=2)
             print(f"[obs] wrote job counters -> {args.metrics_out}", file=sys.stderr)

@@ -14,8 +14,7 @@ while-trip-count-corrected static walk of the compiled module — XLA's raw
 scanned count step by ~trip-count×; see the hlo_analysis module docstring).
 
 The miner's useful-FLOPs estimate (2·n·items·K/256 packed word ops) lives in
-``launch.mine_dryrun`` and in ``launch.mine --metrics-out``'s static_cost
-block (DESIGN.md §13); the ratio useful / HLO_FLOPs catches padding and
+``launch.mine_dryrun``; the ratio useful / HLO_FLOPs catches padding and
 dispatch overhead.
 """
 

@@ -9,7 +9,7 @@ from .registry import (
     MetricsRegistry,
     Sampler,
 )
-from .trace import Span, Tracer
+from .trace import LeafSpan, Span, Tracer
 from .mining import MiningObs, MiningProgress, PHASES
 from .slo import (
     AlertEvent,
@@ -28,6 +28,7 @@ __all__ = [
     "DEFAULT_RULES",
     "Gauge",
     "Histogram",
+    "LeafSpan",
     "MetricsRegistry",
     "MiningObs",
     "MiningProgress",

@@ -14,12 +14,32 @@ None`` so the uninstrumented path stays untouched, and nothing here feeds
 back into mining decisions — mined dicts are bit-identical with obs on/off
 (CI-enforced).
 
-Phase taxonomy (the per-phase wall-time split):
+Phase taxonomy (the per-phase wall-time split, ``mine_phase_seconds``).
+The leaves of host work are :class:`~repro.obs.trace.LeafSpan`s
+``repro.<layer>.<phase>``, on the profiler's clock in any capture:
 
-- ``candidate_gen``   — host-side k-itemset join from the (k-1) survivors
-- ``prefetch_stall``  — time the fold blocked on the chunk iterator
-- ``count_kernel``    — dispatch of the jit'd accumulate step
-- ``host_sync``       — final device→host sync of the level's counts
+- ``candidate_gen``   (``repro.level``)  — host-side k-itemset join from the
+                                          (k-1) survivors
+- ``candidate_place`` (``repro.level``)  — encoding a candidate pass and
+                                          placing it on the device
+- ``chunk_read``      (``repro.stream``) — a chunk's rows out of the store's
+                                          mmap: slice, concatenate, pad (once
+                                          more per pass, for the read that
+                                          finds the store's end)
+- ``chunk_unpack``    (``repro.stream``) — packed words to dense int8
+                                          (dense representation only)
+- ``chunk_place``     (``repro.stream``) — the chunk's host→device transfer,
+                                          as far as the host thread waits
+- ``count_dispatch``  (``repro.stream``) — dispatch of the jit'd accumulate
+                                          step (asynchronous: not kernel time)
+- ``host_sync``       (``repro.level``)  — final device→host sync of a pass
+
+The read, unpack and place run on the chunk producer's thread. Two more
+phases are timed by the caller and kept off the profiler:
+
+- ``prefetch_stall``  — the fold blocked on the chunk producer: a thread
+                        waiting on another, which would overlap, and so
+                        name, every gap the producer causes
 - ``checkpoint_write``— mid-level cursor/accumulator saves
 """
 
@@ -27,12 +47,14 @@ from __future__ import annotations
 
 import sys
 import time
+from contextlib import contextmanager, nullcontext
 from typing import Optional
 
 from .registry import MetricsRegistry
-from .trace import Span, Tracer
+from .trace import LeafSpan, Span, Tracer
 
-PHASES = ("candidate_gen", "prefetch_stall", "count_kernel", "host_sync",
+PHASES = ("candidate_gen", "candidate_place", "chunk_read", "chunk_unpack",
+          "chunk_place", "count_dispatch", "host_sync", "prefetch_stall",
           "checkpoint_write")
 
 
@@ -106,6 +128,9 @@ class MiningObs:
         self.tracer = tracer
         self.progress = progress
         self._level_span: Optional[Span] = None
+        # the phase that ran before its level's root span existed (candidate
+        # generation), added to the level trace when the root starts
+        self._held: Optional[tuple] = None
 
     # -- level lifecycle ---------------------------------------------------
 
@@ -116,6 +141,9 @@ class MiningObs:
         if self.tracer is not None:
             self._level_span = self.tracer.root("mine.level", level=level,
                                                 candidates=candidates)
+            held, self._held = self._held, None
+            if held is not None:
+                self._trace(*held)
         if self.progress is not None:
             self.progress.on_level_start(level, candidates)
 
@@ -130,11 +158,31 @@ class MiningObs:
 
     # -- phase + chunk accounting -----------------------------------------
 
+    @contextmanager
+    def span(self, layer: str, phase: str):
+        """Time one leaf phase as the :class:`LeafSpan`
+        ``repro.<layer>.<phase>``, folded into the phase's wall-time and,
+        when tracing, the level trace."""
+        with LeafSpan(layer, phase, self._seconds(phase)) as sp:
+            yield
+        self._trace(phase, sp.t0, sp.t1)
+
     def add_phase(self, phase: str, t0: float, t1: float) -> None:
-        """Fold one measured interval (``perf_counter`` endpoints) into the
-        phase's cumulative wall-time and, when tracing, the level trace."""
-        self.registry.gauge("mine_phase_seconds", {"phase": phase}).inc(t1 - t0)
-        if self.tracer is not None and self._level_span is not None:
+        """Fold an interval timed by the caller (``perf_counter``
+        endpoints) — one that must stay off the profiler — into the phase's
+        wall-time and, when tracing, the level trace."""
+        self._seconds(phase).inc(t1 - t0)
+        self._trace(phase, t0, t1)
+
+    def _seconds(self, phase: str):
+        return self.registry.gauge("mine_phase_seconds", {"phase": phase})
+
+    def _trace(self, phase: str, t0: float, t1: float) -> None:
+        if self.tracer is None:
+            return
+        if self._level_span is None:
+            self._held = (phase, t0, t1)
+        else:
             self.tracer.add_span(self._level_span, f"mine.{phase}", t0, t1)
 
     def on_chunk(self, rows: int) -> None:
@@ -172,3 +220,9 @@ class MiningObs:
     def finish(self) -> None:
         if self.progress is not None:
             self.progress.finish()
+
+
+def phase(obs: Optional[MiningObs], layer: str, name: str):
+    """``obs.span(layer, name)``, or a null context when mining runs
+    without observation."""
+    return nullcontext() if obs is None else obs.span(layer, name)

@@ -15,6 +15,10 @@ sites never branch.
 
 Span ends are idempotent: failure paths can ``end()`` a span that a success
 path may also try to close, and only the first close is recorded.
+
+:class:`LeafSpan` is the other clock: a leaf of host work written into the
+JAX profiler's capture, beside the device's ops, and folded into a registry
+gauge over the same interval.
 """
 
 from __future__ import annotations
@@ -26,6 +30,8 @@ import time
 from collections import deque
 from contextlib import contextmanager
 from typing import Dict, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 class Span:
@@ -66,6 +72,41 @@ class Span:
 
     def duration_s(self) -> float:
         return (self.t1 - self.t0) if self.t1 is not None else 0.0
+
+
+class LeafSpan:
+    """One leaf of host work on the device trace's clock.
+
+    ``with LeafSpan("stream", "chunk_read", gauge):`` opens a
+    ``jax.profiler.TraceAnnotation`` named ``repro.stream.chunk_read`` (a
+    host event in any profiler capture, on the clock of the device's ops)
+    and on close adds the same interval's ``perf_counter`` seconds to
+    ``gauge`` (``None``: the annotation alone). The interval stays on the
+    object as ``t0``/``t1``. Outside a capture it costs about a microsecond.
+
+    Leaf work only. A capture names each idle gap of the device after the
+    host event that overlaps it most, so a span around a whole job, pass
+    or dispatch, or one on a thread that only waits for another, would win
+    every gap and hide its cause.
+    """
+
+    __slots__ = ("name", "t0", "t1", "_gauge", "_ann")
+
+    def __init__(self, layer: str, phase: str, gauge=None):
+        self.name = f"repro.{layer}.{phase}"
+        self._gauge = gauge
+
+    def __enter__(self) -> "LeafSpan":
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        if self._gauge is not None:
+            self._gauge.inc(self.t1 - self.t0)
 
 
 class Tracer:

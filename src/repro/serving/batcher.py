@@ -41,6 +41,8 @@ from concurrent.futures import Future
 
 import numpy as np
 
+from repro.obs.trace import LeafSpan
+
 _SENTINEL = object()
 
 
@@ -225,28 +227,35 @@ class MicroBatcher:
                     self._metrics.record_response(0.0, failed=True)
 
     # ------------------------------------------------------------- worker --
+    def _wait_span(self) -> LeafSpan:
+        if self._metrics is None:
+            return LeafSpan("batcher", "wait")
+        return self._metrics.span("worker_wait_s")
+
     def _run(self) -> None:
         stop = False
         while not stop:
-            item = self._q.get()
-            if item is _SENTINEL:
-                break
-            batch = [item]
-            wait_s = (self._wait_controller.current_wait_s()
-                      if self._wait_controller is not None else self._max_wait_s)
-            deadline = time.perf_counter() + wait_s
-            while len(batch) < self._max_batch:
-                remaining = deadline - time.perf_counter()
-                try:
-                    # past the deadline we still drain whatever is already
-                    # queued (free batching), we just stop *waiting*
-                    nxt = self._q.get_nowait() if remaining <= 0 else self._q.get(timeout=remaining)
-                except queue.Empty:
+            with self._wait_span():
+                item = self._q.get()
+                if item is _SENTINEL:
                     break
-                if nxt is _SENTINEL:
-                    stop = True
-                    break
-                batch.append(nxt)
+                batch = [item]
+                wait_s = (self._wait_controller.current_wait_s()
+                          if self._wait_controller is not None else self._max_wait_s)
+                deadline = time.perf_counter() + wait_s
+                while len(batch) < self._max_batch:
+                    remaining = deadline - time.perf_counter()
+                    try:
+                        # past the deadline we still drain whatever is already
+                        # queued (free batching), we just stop *waiting*
+                        nxt = (self._q.get_nowait() if remaining <= 0
+                               else self._q.get(timeout=remaining))
+                    except queue.Empty:
+                        break
+                    if nxt is _SENTINEL:
+                        stop = True
+                        break
+                    batch.append(nxt)
             self._dispatch_tracked(batch)
         # defensive flush: the admit lock orders every admitted request
         # ahead of the sentinel, so this drain should always be empty

@@ -118,7 +118,7 @@ class Gateway:
         faster. ``None`` keeps the classic fixed wait.
 
         ``tracer``: optional :class:`repro.obs.Tracer`; sampled requests get
-        cache-probe / queue-wait / batch-assembly / device-dispatch spans.
+        cache-probe / queue-wait / launch / fetch spans.
         ``trace_root=False`` (the router's replicas) makes the gateway only
         ever CONTINUE a trace handed in by its caller, never start one —
         sampling then happens once, at the router."""
@@ -369,6 +369,8 @@ class Gateway:
         """Compile jit buckets for this generation's rule count (jit keys on
         the rulebook row count) off the serving path: the ladder endpoints,
         or with ``warmup="ladder"`` every power-of-two bucket."""
+        import jax
+
         if self._warmup_enabled == "ladder":
             ns = {1 << p for p in range(self.max_batch.bit_length())
                   if 1 << p <= self.max_batch} | {self.max_batch}
@@ -376,10 +378,12 @@ class Gateway:
             ns = {1, self.max_batch}
         for n in sorted(ns):
             bucket = pow2_bucket(n, self.max_batch, self._row_multiple)
-            self._match(np.zeros((bucket, self._words), np.uint32), gen, self.default_top_k)
+            jax.block_until_ready(self._launch(
+                np.zeros((bucket, self._words), np.uint32), gen, self.default_top_k))
 
-    def _match(self, b: np.ndarray, gen: _Generation, top_k: int):
-        """Pad-free core: run one padded bucket through match + top-k."""
+    def _launch(self, b: np.ndarray, gen: _Generation, top_k: int):
+        """Place one padded bucket and dispatch match + top-k; returns the
+        device arrays (item ids, scores) without waiting for them."""
         import jax
         import jax.numpy as jnp
 
@@ -389,52 +393,56 @@ class Gateway:
         else:
             b_dev = jnp.asarray(b)
         item_scores = self._step(b_dev, rb.ante_packed, rb.ante_len, rb.cons_packed, rb.scores)
-        idx, vals = _topk_items(
+        return _topk_items(
             item_scores, b_dev,
             top_k=top_k, exclude_basket=self.exclude_basket, num_items=self.num_items,
         )
-        return np.asarray(idx), np.asarray(vals)
 
     def _dispatch(self, group: list) -> None:
         """Batcher callback: one coalesced same-top_k group -> responses.
 
         The generation reference is read ONCE per dispatch — the whole batch
         is answered by a single rulebook, so responses can never mix
-        generations within a batch."""
+        generations within a batch. Launch, fetch and respond are the
+        metrics' leaf spans (``GatewayMetrics``)."""
         gen = self._generation
         k = group[0].top_k
-        t_drain = time.perf_counter()
-        bucket = pow2_bucket(len(group), self.max_batch, self._row_multiple)
-        b = np.zeros((bucket, self._words), np.uint32)
-        for i, r in enumerate(group):
-            b[i] = r.packed
-        t_asm = time.perf_counter()
-        idx, vals = self._match(b, gen, k)
-        t_dev = time.perf_counter()
-        tr = self._tracer
-        if tr is not None:
-            for r in group:
+        m = self.metrics
+        with m.span("dispatch_launch_s") as launch:
+            bucket = pow2_bucket(len(group), self.max_batch, self._row_multiple)
+            b = np.zeros((bucket, self._words), np.uint32)
+            for i, r in enumerate(group):
+                b[i] = r.packed
+            idx, vals = self._launch(b, gen, k)
+        with m.span("dispatch_fetch_s") as fetch:
+            idx, vals = np.asarray(idx), np.asarray(vals)
+        with m.span("dispatch_respond_s"):
+            t_drain = launch.t0
+            tr = self._tracer
+            if tr is not None:
+                for r in group:
+                    if r.span is not None:
+                        tr.add_span(r.span, "queue.wait", r.t_submit, t_drain)
+                        tr.add_span(r.span, "gateway.launch", t_drain, launch.t1,
+                                    batch=len(group), bucket=bucket)
+                        tr.add_span(r.span, "gateway.fetch", fetch.t0, fetch.t1,
+                                    bucket=bucket)
+            m.record_batch(len(group), bucket, sum(t_drain - r.t_submit for r in group))
+            now = time.perf_counter()
+            for i, r in enumerate(group):
+                items, scores = idx[i], vals[i]
+                self.cache.put(
+                    basket_key(r.packed, k, gen.generation),
+                    (items, scores, gen.generation, bucket),
+                )
+                latency = now - r.t_submit
+                m.record_response(latency)
                 if r.span is not None:
-                    tr.add_span(r.span, "queue.wait", r.t_submit, t_drain)
-                    tr.add_span(r.span, "batch.assemble", t_drain, t_asm,
-                                batch=len(group), bucket=bucket)
-                    tr.add_span(r.span, "device.dispatch", t_asm, t_dev,
-                                bucket=bucket)
-        self.metrics.record_batch(len(group), bucket)
-        now = time.perf_counter()
-        for i, r in enumerate(group):
-            items, scores = idx[i], vals[i]
-            self.cache.put(
-                basket_key(r.packed, k, gen.generation),
-                (items, scores, gen.generation, bucket),
-            )
-            latency = now - r.t_submit
-            self.metrics.record_response(latency)
-            if r.span is not None:
-                # the per-request "where did the time go" breakdown the p99
-                # bench row reads straight off the root span (§13)
-                r.span.end(outcome="ok", generation=gen.generation, bucket=bucket,
-                           queue_ms=(t_drain - r.t_submit) * 1e3,
-                           batch_ms=(t_asm - t_drain) * 1e3,
-                           device_ms=(t_dev - t_asm) * 1e3)
-            r.future.set_result(Response(items, scores, gen.generation, False, latency, bucket))
+                    # the per-request "where did the time go" breakdown the p99
+                    # bench row reads straight off the root span (§13)
+                    r.span.end(outcome="ok", generation=gen.generation, bucket=bucket,
+                               queue_ms=(t_drain - r.t_submit) * 1e3,
+                               launch_ms=(launch.t1 - t_drain) * 1e3,
+                               fetch_ms=(fetch.t1 - fetch.t0) * 1e3)
+                r.future.set_result(
+                    Response(items, scores, gen.generation, False, latency, bucket))

@@ -28,6 +28,7 @@ from repro.obs.registry import (
     Histogram,
     MetricsRegistry,
 )
+from repro.obs.trace import LeafSpan
 
 
 class LatencyHistogram(Histogram):
@@ -83,7 +84,19 @@ class _RegistryMetrics:
 
 
 class GatewayMetrics(_RegistryMetrics):
-    """All gateway counters + the request-latency histogram, one lock."""
+    """All gateway counters + the request-latency histogram, one lock.
+
+    The dispatch worker's host seconds, split four ways that cover its loop
+    end to end, each a :class:`~repro.obs.trace.LeafSpan` on the profiler's
+    clock: ``worker_wait_s`` (blocked on an empty queue, then collecting
+    its batch; ``repro.batcher.wait``), ``dispatch_launch_s`` (drain to
+    launched: assembly, basket transfer, match + top-k dispatch;
+    ``repro.gateway.launch``), ``dispatch_fetch_s`` (the answers to the
+    host; ``repro.gateway.fetch``) and ``dispatch_respond_s`` (cache puts,
+    response accounting, futures and their callbacks;
+    ``repro.gateway.respond``). ``queue_wait_s`` sums each dispatched
+    request's submit → drain wait, added once per dispatch.
+    """
 
     _COUNTER_FIELDS = (
         "submitted",          # admitted into the queue (or served from cache)
@@ -99,9 +112,17 @@ class GatewayMetrics(_RegistryMetrics):
         "batch_rows_real",    # requests actually in dispatched batches
         "batch_rows_padded",  # rows of the padded jit buckets
     )
+    _SPANS = {
+        "worker_wait_s": ("batcher", "wait"),
+        "dispatch_launch_s": ("gateway", "launch"),
+        "dispatch_fetch_s": ("gateway", "fetch"),
+        "dispatch_respond_s": ("gateway", "respond"),
+    }
 
     def __init__(self, registry: Optional[MetricsRegistry] = None):
         super().__init__(registry, prefix="gateway")
+        self._seconds = {f: self.registry.gauge(f"gateway_{f[:-2]}_seconds")
+                         for f in (*self._SPANS, "queue_wait_s")}
         self.generation_age = self.registry.register(
             GenerationAgeGauge("gateway_generation_age_seconds",
                                lock=self.registry.lock))
@@ -117,11 +138,17 @@ class GatewayMetrics(_RegistryMetrics):
     def record_cache(self, hit: bool) -> None:
         self._inc("cache_hits" if hit else "cache_misses")
 
-    def record_batch(self, real_rows: int, padded_rows: int) -> None:
+    def span(self, field: str) -> LeafSpan:
+        """The leaf span that times one of the worker's ``_SPANS`` fields."""
+        return LeafSpan(*self._SPANS[field], self._seconds[field])
+
+    def record_batch(self, real_rows: int, padded_rows: int,
+                     queue_wait_s: float = 0.0) -> None:
         with self._lock:
             self._inc("batches")
             self._inc("batch_rows_real", real_rows)
             self._inc("batch_rows_padded", padded_rows)
+            self._seconds["queue_wait_s"].inc(queue_wait_s)
 
     def record_response(self, latency_s: float, failed: bool = False) -> None:
         if failed:
@@ -163,6 +190,7 @@ class GatewayMetrics(_RegistryMetrics):
         # histogram (they share the registry lock): a fully atomic cut.
         with self._lock:
             out = {f: self._counters[f].value for f in self._COUNTER_FIELDS}
+            out.update((f, g.value) for f, g in self._seconds.items())
             out["generation_age_s"] = self.generation_age.value
             out["batch_occupancy"] = (
                 out["batch_rows_real"] / out["batch_rows_padded"]

@@ -85,10 +85,13 @@ def make_match_step(
     Single-device: a jit around ``kernels.ops.rule_match``.  Mesh: the
     Map/Reduce form — baskets sharded ``P(data_axes, None)``, rulebook
     columns ``P(rule_axis, ...)``, partial item scores psum'd over the rule
-    axis (replicated result rows stay sharded over the data axes).
+    axis (replicated result rows stay sharded over the data axes). Its ops
+    carry the name scope ``repro.rule_match``, whatever implements the match.
     """
     def local_match(b, a, ln, c, s):
-        return kops.rule_match(b, a, ln, c, s, impl=impl, block_n=block_n, block_k=block_k)
+        with jax.named_scope("repro.rule_match"):
+            return kops.rule_match(b, a, ln, c, s, impl=impl, block_n=block_n,
+                                   block_k=block_k)
 
     if mesh is None or math.prod(mesh.shape.values()) == 1:
         return jax.jit(local_match)

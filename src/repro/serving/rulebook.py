@@ -28,6 +28,7 @@ import dataclasses
 import numpy as np
 
 from repro.core import rules as rules_mod
+from repro.obs.trace import LeafSpan
 
 SCORE_KINDS = ("confidence", "lift")
 
@@ -88,34 +89,36 @@ def compile_rulebook(
     pad_multiple: int = 256,
 ) -> Rulebook:
     """Vectorized extraction (``core.rules.extract_rule_arrays``) -> sorted,
-    truncated, padded serving columns."""
+    truncated, padded serving columns, as the host span
+    ``repro.rulebook.compile``."""
     if score not in SCORE_KINDS:
         raise ValueError(f"score must be one of {SCORE_KINDS}, got {score!r}")
-    arr = rules_mod.extract_rule_arrays(result, min_confidence, num_items)
-    scores = np.asarray(arr.confidence if score == "confidence" else arr.lift, np.float32)
+    with LeafSpan("rulebook", "compile"):
+        arr = rules_mod.extract_rule_arrays(result, min_confidence, num_items)
+        scores = np.asarray(arr.confidence if score == "confidence" else arr.lift, np.float32)
 
-    # descending score, bitset tie-break (np.lexsort: last key is primary)
-    keys = (
-        [arr.cons_packed[:, w] for w in range(arr.cons_packed.shape[1] - 1, -1, -1)]
-        + [arr.ante_packed[:, w] for w in range(arr.ante_packed.shape[1] - 1, -1, -1)]
-        + [-scores.astype(np.float64)]
-    )
-    order = np.lexsort(keys)
-    if max_rules is not None:
-        order = order[:max_rules]
+        # descending score, bitset tie-break (np.lexsort: last key is primary)
+        keys = (
+            [arr.cons_packed[:, w] for w in range(arr.cons_packed.shape[1] - 1, -1, -1)]
+            + [arr.ante_packed[:, w] for w in range(arr.ante_packed.shape[1] - 1, -1, -1)]
+            + [-scores.astype(np.float64)]
+        )
+        order = np.lexsort(keys)
+        if max_rules is not None:
+            order = order[:max_rules]
 
-    r = order.size
-    rp = max(pad_multiple, ((r + pad_multiple - 1) // pad_multiple) * pad_multiple)
-    w = arr.ante_packed.shape[1]
-    ante = np.zeros((rp, w), np.uint32)
-    cons = np.zeros((rp, w), np.uint32)
-    lens = np.full(rp, -1, np.int32)
-    sc = np.zeros(rp, np.float32)
-    ante[:r] = arr.ante_packed[order]
-    cons[:r] = arr.cons_packed[order]
-    lens[:r] = arr.ante_len[order]
-    sc[:r] = scores[order]
-    return Rulebook(ante, cons, lens, sc, arr.num_items, score, min_confidence)
+        r = order.size
+        rp = max(pad_multiple, ((r + pad_multiple - 1) // pad_multiple) * pad_multiple)
+        w = arr.ante_packed.shape[1]
+        ante = np.zeros((rp, w), np.uint32)
+        cons = np.zeros((rp, w), np.uint32)
+        lens = np.full(rp, -1, np.int32)
+        sc = np.zeros(rp, np.float32)
+        ante[:r] = arr.ante_packed[order]
+        cons[:r] = arr.cons_packed[order]
+        lens[:r] = arr.ante_len[order]
+        sc[:r] = scores[order]
+        return Rulebook(ante, cons, lens, sc, arr.num_items, score, min_confidence)
 
 
 def place_rulebook(rb: Rulebook, mesh, rule_axis: str = "model") -> Rulebook:

@@ -191,8 +191,8 @@ def test_batcher_backpressure_rejects_are_reported():
 def test_gateway_backpressure_counts_are_consistent(rulebooks, baskets):
     rb0, _ = rulebooks
     gw = Gateway(rb0, max_batch=4, max_wait_ms=0.0, queue_depth=2, cache_capacity=0)
-    real_match = gw._match
-    gw._match = lambda *a, **kw: (time.sleep(0.03), real_match(*a, **kw))[1]
+    real_launch = gw._launch
+    gw._launch = lambda *a, **kw: (time.sleep(0.03), real_launch(*a, **kw))[1]
     futs, rejected = [], 0
     for i in range(60):
         try:
@@ -324,6 +324,56 @@ def test_occupancy_counts_real_vs_padded(rulebooks, baskets):
         assert s["batch_rows_padded"] >= 5
         assert 0.0 < s["batch_occupancy"] <= 1.0
         assert s["latency"]["count"] == 5
+
+
+WORKER_FIELDS = ("worker_wait_s", "dispatch_launch_s", "dispatch_fetch_s",
+                 "dispatch_respond_s")
+
+
+def test_dispatch_worker_seconds_grow_once_per_dispatch_and_reconcile(rulebooks, baskets):
+    """The worker's loop is timed as wait + launch + fetch + respond, each
+    folded once per dispatch (the wait once more, for the wait the close
+    ends), plus the queue wait summed over each dispatch's requests. They
+    appear in stats(), and together they fit in the worker's wall time."""
+    rb0, _ = rulebooks
+    t0 = time.perf_counter()
+    gw = Gateway(rb0, max_batch=8, max_wait_ms=0.0, cache_capacity=0)
+    folds = {f: 0 for f in (*WORKER_FIELDS, "queue_wait_s")}
+    for f, gauge in gw.metrics._seconds.items():
+        def counted(n, _f=f, _inc=gauge.inc):
+            folds[_f] += 1
+            _inc(n)
+        gauge.inc = counted
+    t_built = time.perf_counter()
+    for b in baskets[:6]:
+        gw.query(b, top_k=5)
+    futs = [gw.submit(b, top_k=5) for b in baskets[6:30]]
+    for f in futs:
+        f.result(timeout=60)
+    gw.close()
+    wall = time.perf_counter() - t0
+    s = gw.stats()
+    n = s["batches"]
+    assert 7 <= n <= 30
+    for f in (*WORKER_FIELDS, "queue_wait_s"):
+        assert isinstance(s[f], float), f
+    assert folds == {"worker_wait_s": n + 1, "dispatch_launch_s": n, "dispatch_fetch_s": n,
+                     "dispatch_respond_s": n, "queue_wait_s": n}
+    assert all(s[f] > 0.0 for f in WORKER_FIELDS)
+    assert 0.0 <= s["queue_wait_s"]
+    assert sum(s[f] for f in WORKER_FIELDS) <= wall
+    # the worker started after the warm-up: everything it did fits after it
+    assert sum(s[f] for f in WORKER_FIELDS[1:]) <= time.perf_counter() - t_built
+
+
+def test_queue_wait_is_summed_per_request():
+    m = GatewayMetrics()
+    m.record_batch(3, 4, queue_wait_s=0.006)
+    m.record_batch(1, 4, queue_wait_s=0.001)
+    snap = m.snapshot()
+    assert snap["queue_wait_s"] == pytest.approx(0.007)
+    assert snap["batches"] == 2 and snap["batch_rows_real"] == 4
+    assert {f: snap[f] for f in WORKER_FIELDS} == dict.fromkeys(WORKER_FIELDS, 0.0)
 
 
 # ------------------------------------------------------------------ bucket --

@@ -59,13 +59,13 @@ def test_mine_streamed_obs_parity_and_counters(store):
     assert snap["mine_rows_streamed"] >= store.num_transactions
     assert snap["mine_chunks_streamed"] > 0
     # all five phases of the wall-time split are populated
-    for phase in ("candidate_gen", "prefetch_stall", "count_kernel", "host_sync"):
+    for phase in ("candidate_gen", "prefetch_stall", "count_dispatch", "host_sync"):
         assert snap[f'mine_phase_seconds{{phase="{phase}"}}'] > 0.0, phase
     # the trace shows one mine.level root per attempted level, phase children
     roots = [s for s in tracer.spans() if s.name == "mine.level"]
     assert len(roots) == snap["mine_levels"]
     kinds = {s.name for s in tracer.spans()}
-    assert {"mine.candidate_gen", "mine.count_kernel", "mine.prefetch_stall"} <= kinds
+    assert {"mine.candidate_gen", "mine.count_dispatch", "mine.prefetch_stall"} <= kinds
 
 
 def test_mine_son_streamed_obs_parity_and_fault_counters(store):
@@ -170,3 +170,46 @@ def test_router_stats_aggregate_replica_histograms_by_merge(small_db):
         per_replica = [r["gateway"]["latency"]["count"] for r in stats["replicas"]]
         assert merged["count"] == sum(per_replica) == len(baskets)
         assert merged["p99_ms"] >= merged["p50_ms"] >= 0.0
+
+
+@pytest.mark.parametrize("rep", ["dense", "packed"])
+def test_every_leaf_phase_counted_once_per_chunk_or_pass(store, rep):
+    """Each chunk is read, (dense only) unpacked, placed and dispatched once;
+    each candidate pass is placed and synced once; the read that finds the
+    store's end adds one chunk_read per pass. The mined dict is identical
+    with obs on and off."""
+    cfg = AprioriConfig(min_support=0.02, max_k=3, count_impl="jnp", representation=rep)
+    plain = mine_streamed(store, cfg, chunk_rows=512)
+    tracer = Tracer(sample_rate=1.0)
+    obs = MiningObs(registry=MetricsRegistry(), tracer=tracer)
+    inst = mine_streamed(store, cfg, chunk_rows=512, obs=obs)
+    _assert_same_result(plain, inst)
+
+    snap = obs.counters()
+    chunks, levels = snap["mine_chunks_streamed"], snap["mine_levels"]
+    passes = chunks // -(-store.num_transactions // 512)
+    assert passes >= levels
+    n = {}
+    for s in tracer.spans():
+        n[s.name] = n.get(s.name, 0) + 1
+    want = {"mine.chunk_read": chunks + passes, "mine.chunk_place": chunks,
+            "mine.count_dispatch": chunks, "mine.prefetch_stall": chunks,
+            "mine.candidate_place": passes, "mine.host_sync": passes,
+            "mine.candidate_gen": levels,
+            "mine.chunk_unpack": chunks if rep == "dense" else 0}
+    assert {k: n.get(k, 0) for k in want} == want
+    for phase in want:
+        if want[phase]:
+            assert snap[f'mine_phase_seconds{{phase="{phase[5:]}"}}'] > 0.0, phase
+    assert 'mine_phase_seconds{phase="chunk_unpack"}' not in snap or rep == "dense"
+
+
+def test_son_union_pass_counts_its_leaf_phases(store):
+    """The one-scan union count of SON's phase 2 times the same leaves."""
+    obs = MiningObs(registry=MetricsRegistry())
+    plain = mine_son_streamed(store, CFG, chunk_rows=512)
+    _assert_same_result(plain, mine_son_streamed(store, CFG, chunk_rows=512, obs=obs))
+    snap = obs.counters()
+    for phase in ("chunk_read", "chunk_unpack", "chunk_place", "count_dispatch",
+                  "candidate_place", "host_sync", "prefetch_stall"):
+        assert snap[f'mine_phase_seconds{{phase="{phase}"}}'] > 0.0, phase

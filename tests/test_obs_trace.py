@@ -71,12 +71,12 @@ def test_add_span_records_elapsed_interval():
     root = tr.root("level")
     t0 = time.perf_counter()
     t1 = t0 + 0.25
-    tr.add_span(root, "count_kernel", t0, t1, chunk=3)
+    tr.add_span(root, "count_dispatch", t0, t1, chunk=3)
     root.end()
-    kernel = next(s for s in tr.spans() if s.name == "count_kernel")
-    assert kernel.parent_id == root.span_id
-    assert kernel.duration_s() == 0.25
-    assert kernel.attrs["chunk"] == 3
+    dispatch = next(s for s in tr.spans() if s.name == "count_dispatch")
+    assert dispatch.parent_id == root.span_id
+    assert dispatch.duration_s() == 0.25
+    assert dispatch.attrs["chunk"] == 3
 
 
 def test_chrome_export_schema(tmp_path):

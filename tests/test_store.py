@@ -7,7 +7,7 @@ import os
 import numpy as np
 import pytest
 
-from repro.core.itemsets import pack_bits, packed_words
+from repro.core.itemsets import pack_bits, packed_words, unpack_bits
 from repro.data import store as st
 from repro.data.synthetic import QuestConfig, gen_transactions
 
@@ -112,16 +112,16 @@ def test_iter_chunks_covers_all_rows_across_shards(tmp_path, chunk_rows):
     dense = _rand_dense(100, 37, seed=5)
     s = st.ingest_dense(dense, str(tmp_path / "db"), shard_rows=30)
     got = []
-    for chunk, valid in s.iter_chunks(chunk_rows, representation="dense"):
+    for chunk, valid in s.iter_chunks(chunk_rows):
         assert valid == chunk.shape[0] <= chunk_rows
-        got.append(chunk)
+        got.append(unpack_bits(chunk, 37))
     assert np.array_equal(np.concatenate(got), dense)
 
 
 def test_iter_chunks_packed_matches_pack_bits(tmp_path):
     dense = _rand_dense(64, 48, seed=6)
     s = st.ingest_dense(dense, str(tmp_path / "db"), shard_rows=25)
-    got = np.concatenate([c for c, _ in s.iter_chunks(17, representation="packed")])
+    got = np.concatenate([c for c, _ in s.iter_chunks(17)])
     assert np.array_equal(got, pack_bits(dense))
 
 
@@ -130,7 +130,7 @@ def test_iter_chunks_pad_fixed_shape(tmp_path):
     (inert rows, DESIGN.md §3) — the fixed jit shape the streamer relies on."""
     dense = _rand_dense(50, 32, seed=7)
     s = st.ingest_dense(dense, str(tmp_path / "db"), shard_rows=20)
-    chunks = list(s.iter_chunks(16, representation="packed", pad=True))
+    chunks = list(s.iter_chunks(16, pad=True))
     assert [c.shape[0] for c, _ in chunks] == [16, 16, 16, 16]
     assert [v for _, v in chunks] == [16, 16, 16, 2]
     last, valid = chunks[-1]
@@ -145,7 +145,7 @@ def test_iter_chunks_rejects_bad_args(tmp_path):
     with pytest.raises(ValueError):
         list(s.iter_chunks(0))
     with pytest.raises(ValueError):
-        list(s.iter_chunks(4, representation="sparse"))
+        list(s.iter_chunks(4, start_chunk=-1))
 
 
 # ------------------------------------------------------- chunk cursor seek ----
@@ -157,10 +157,9 @@ def test_iter_chunks_start_chunk_equals_skipping(tmp_path, chunk_rows, shard_row
     both ways. This is what makes a checkpointed chunk index replayable."""
     dense = _rand_dense(100, 37, seed=8)
     s = st.ingest_dense(dense, str(tmp_path / "db"), shard_rows=shard_rows)
-    full = list(s.iter_chunks(chunk_rows, representation="packed", pad=True))
+    full = list(s.iter_chunks(chunk_rows, pad=True))
     for k in range(len(full) + 1):
-        tail = list(s.iter_chunks(chunk_rows, representation="packed",
-                                  pad=True, start_chunk=k))
+        tail = list(s.iter_chunks(chunk_rows, pad=True, start_chunk=k))
         assert len(tail) == len(full) - k
         for (want, wv), (got, gv) in zip(full[k:], tail):
             assert wv == gv
@@ -280,7 +279,7 @@ def test_iter_chunks_shard_range(tmp_path):
     s = st.ingest_dense(dense, p, shard_rows=17)
     rows = s.manifest.shard_rows
     for s0, s1 in [(0, 2), (2, 5), (0, s.num_partitions), (3, 3)]:
-        got = [c for c, v in s.iter_chunks(7, representation="dense", shards=(s0, s1))]
+        got = [unpack_bits(c, 16) for c, v in s.iter_chunks(7, shards=(s0, s1))]
         lo = sum(rows[:s0]); hi = lo + sum(rows[s0:s1])
         want = dense[lo:hi]
         assert np.array_equal(np.concatenate(got) if got else np.zeros((0, 16)), want)

@@ -78,6 +78,30 @@ def test_multi_pass_candidate_split(tmp_path):
     np.testing.assert_array_equal(got, want)
 
 
+@pytest.mark.parametrize("observed", [False, True])
+@pytest.mark.parametrize("rep", ["dense", "packed"])
+@pytest.mark.parametrize("where", [{}, {"start_chunk": 2}, {"shards": (1, 3)}])
+def test_observed_producer_yields_the_same_chunks(tmp_path, rep, where, observed):
+    """The producer reads the store's packed words and unpacks them for the
+    dense step as a step of its own: with or without a MiningObs its chunks
+    are byte-identical to the store's, unpacked, for every representation,
+    cursor and shard range."""
+    from repro.core.itemsets import unpack_bits
+    from repro.obs import MetricsRegistry, MiningObs
+
+    t, _, _ = random_problem(130, 45, 4, seed=4)
+    s = _store_from_dense(t, tmp_path / "db", shard_rows=40)
+    cfg = AprioriConfig(count_impl="jnp", representation=rep)
+    want = [c if rep == "packed" else unpack_bits(c, 45)
+            for c, _ in s.iter_chunks(17, pad=True, **where)]
+    obs = MiningObs(registry=MetricsRegistry()) if observed else None
+    got = list(streaming._chunks(s, 17, cfg, obs, **where))
+    assert len(got) == len(want) > 0
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        assert g.tobytes() == w.tobytes()
+
+
 # --------------------------------------------------- end-to-end equality -----
 @pytest.mark.parametrize("rep", ["dense", "packed"])
 def test_mine_streamed_matches_mine(tmp_path, small_db, rep):
