@@ -147,8 +147,10 @@ def check_pallas_lowering(num_items: int, mesh=None) -> None:
     kp = 1024
     for rep in ("dense", "packed"):
         cfg = mine_cfg(rep, mesh=mesh)
-        width, dtype = ((num_items, jnp.int8) if rep == "dense"
-                        else (packed_words(num_items), jnp.uint32))
+        # the streamed chunk is the store's words for both representations;
+        # a candidate is int8 items (dense) or words (packed)
+        c_width, c_dtype = ((num_items, jnp.int8) if rep == "dense"
+                            else (packed_words(num_items), jnp.uint32))
         shard = {}
         if mesh is not None:
             shard = dict(t=NamedSharding(mesh, P(("data",), None)),
@@ -156,7 +158,8 @@ def check_pallas_lowering(num_items: int, mesh=None) -> None:
                          v=NamedSharding(mesh, P("model")))
         sds = lambda shape, dt, s: jax.ShapeDtypeStruct(shape, dt, sharding=shard.get(s))
         lowered = make_accum_count_step(mesh, cfg).lower(
-            sds((CHUNK_ROWS, width), dtype, "t"), sds((kp, width), dtype, "c"),
+            sds((CHUNK_ROWS, packed_words(num_items)), jnp.uint32, "t"),
+            sds((kp, c_width), c_dtype, "c"),
             sds((kp,), jnp.int32, "v"), sds((kp,), jnp.int32, "v"))
         text = lowered.as_text()
         check("tpu_custom_call" in text, f"{rep} count step has no Pallas kernel")

@@ -117,10 +117,12 @@ def make_count_step(
     Dense:  fn(T (N,I) int8,  C (Kp,I) int8,  lengths (Kp,) int32)
     Packed: fn(T (N,W) uint32, C (Kp,W) uint32, lengths (Kp,) int32)
     with T sharded over data_axes -> counts (Kp,) int32, replicated over the
-    data axes, sharded over model_axis. The sharded path is identical for
-    both representations — P(data_axes, None) over rows, whatever the row
-    payload is (DESIGN.md §2). Its ops carry the name scope
-    ``repro.count_step``, whatever implements the count.
+    data axes, sharded over model_axis. The dense step also takes T as the
+    store's packed words, uint32 (N,W), and unpacks them on the device, per
+    shard, before counting: a uint32 T is words, any other T is rows. The
+    sharded path is identical for both representations — P(data_axes, None)
+    over rows, whatever the row payload is (DESIGN.md §2). Its ops carry the
+    name scope ``repro.count_step``, whatever implements the count.
     """
     if cfg.representation == "packed":
 
@@ -134,6 +136,8 @@ def make_count_step(
 
         def local_count(t, c, ln):
             with jax.named_scope("repro.count_step"):
+                if t.dtype == jnp.uint32:   # the store's packed words
+                    t = kops.unpack_bits_device(t, c.shape[1])
                 return kops.support_count(
                     t, c, ln, impl=cfg.count_impl, operand_dtype=cfg.operand_dtype
                 )

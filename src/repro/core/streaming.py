@@ -6,9 +6,12 @@ module is that dataflow for the miner (DESIGN.md §9): the DB lives in a
 ``data.store.TransactionStore`` (packed uint32 shards on disk), and each
 level's count pass iterates fixed-size row chunks through the SAME jit'd
 count step as the in-memory driver, **accumulating per-candidate partial
-counts on device** — the combiner. The host syncs a candidate pass exactly
-once, after its last chunk, so per level there is a single device→host
-transfer regardless of chunk count.
+counts on device** — the combiner. The chunks go to the device as the
+store's packed words, for both representations: the dense count step
+unpacks them on the device (``kernels.ops.unpack_bits_device``), so the
+host reads and places 4·ceil(I/32) bytes a row and unpacks nothing. The
+host syncs a candidate pass exactly once, after its last chunk, so per
+level there is a single device→host transfer regardless of chunk count.
 
 Host peak RSS is bounded by O(chunk_rows · row_bytes) (plus the candidate
 tensors), not the dataset size: chunks are copied out of the mmap'd shards
@@ -51,7 +54,6 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core import apriori as ap
-from repro.core import itemsets as enc
 from repro.core import son as son_mod
 from repro.data.pipeline import ShardedBatchIterator, batch_spec
 from repro.distributed.checkpoint import (
@@ -105,30 +107,24 @@ def _effective_chunk_rows(chunk_rows: int, cfg: ap.AprioriConfig, mesh) -> int:
     return ((chunk_rows + shards - 1) // shards) * shards
 
 
-def _chunks(store, chunk_rows: int, cfg: ap.AprioriConfig, obs=None, **where):
-    """The store's zero-padded chunks in the count step's representation
-    (``where``: ``start_chunk`` / ``shards`` of ``iter_chunks``).
+def _chunks(store, chunk_rows: int, obs=None, **where):
+    """The store's zero-padded chunks of packed uint32 words, as read, for
+    either representation (``where``: ``start_chunk`` / ``shards`` of
+    ``iter_chunks``); the dense count step unpacks them on the device.
 
-    The store yields packed words; the dense step's chunks are unpacked
-    here, as a step of its own, so that ``repro.stream.chunk_read`` and
-    ``repro.stream.chunk_unpack`` time the two apart on the thread that
-    draws the chunks."""
-    packed = store.iter_chunks(chunk_rows, pad=True, **where)
-    return _unpacked_chunks(packed, store.num_items, cfg.representation, obs)
-
-
-def _unpacked_chunks(packed, num_items: int, representation: str, obs):
+    ``repro.stream.chunk_read`` times each read on the thread that draws the
+    chunks. ``repro.stream.chunk_unpack`` times nothing: no unpack is left
+    on the host, and the span stays empty only while the benchmark's
+    ``chunk_unpack_s.mine`` reader and its span test name it (PERF.md §7)."""
+    chunks = store.iter_chunks(chunk_rows, pad=True, **where)
     while True:
         with phase(obs, "stream", "chunk_read"):
-            nxt = next(packed, None)
+            nxt = next(chunks, None)
         if nxt is None:
             return
-        if representation == "packed":
-            yield nxt[0]
-        else:
-            with phase(obs, "stream", "chunk_unpack"):
-                chunk = enc.unpack_bits(nxt[0], num_items)
-            yield chunk
+        with phase(obs, "stream", "chunk_unpack"):
+            pass
+        yield nxt[0]
 
 
 def _place_span(obs):
@@ -190,7 +186,7 @@ def _count_pass_chunks(
                 obs.add_phase("prefetch_stall", t0, time.perf_counter())
                 with obs.span("stream", "count_dispatch"):
                     acc = accum_step(t_chunk, c_dev, len_dev, acc)
-                obs.on_chunk(int(t_chunk.shape[0]))
+                obs.on_chunk(int(t_chunk.shape[0]), int(t_chunk.nbytes))
                 done += 1
                 if save_fn is not None and save_every > 0 and done % save_every == 0:
                     t3 = time.perf_counter()
@@ -283,7 +279,7 @@ def _count_level_streamed(
                 )
             init_acc, start_chunk = resume_acc, resume_chunks
             resume_acc = None
-        chunks = _chunks(store, chunk_rows, cfg, obs, start_chunk=start_chunk)
+        chunks = _chunks(store, chunk_rows, obs, start_chunk=start_chunk)
         if save_cb is not None and save_every > 0:
             def save_fn(acc_np, done, _start=start):
                 save_cb(counts, _start, acc_np, done)
@@ -466,7 +462,7 @@ def count_union_streamed(
                 c_dev, len_dev = ap._place_candidates(chunk_c, kp, num_items, cfg, mesh)
             units.append([k, start, chunk_c.shape[0], c_dev, len_dev, _init_acc(kp, cfg, mesh)])
     if units:
-        chunks = _chunks(store, chunk_rows, cfg, obs, shards=shards)
+        chunks = _chunks(store, chunk_rows, obs, shards=shards)
         it = ShardedBatchIterator(chunks, mesh, batch_spec(cfg.data_axes), prefetch=prefetch,
                                   place_span=_place_span(obs))
         try:
@@ -486,7 +482,7 @@ def count_union_streamed(
                     with obs.span("stream", "count_dispatch"):
                         for u in units:
                             u[5] = accum_step(t_chunk, u[3], u[4], u[5])
-                    obs.on_chunk(int(t_chunk.shape[0]))
+                    obs.on_chunk(int(t_chunk.shape[0]), int(t_chunk.nbytes))
         finally:
             it.close()
 

@@ -192,7 +192,8 @@ class TransactionStore:
         """Yield ``(chunk, valid_rows)`` covering all n rows in order.
 
         chunk: (chunk_rows or fewer, words) uint32 packed rows (the miner
-        unpacks them for its dense step, ``core.streaming``). Chunks are
+        ships them as read for either representation; its dense count step
+        unpacks them on the device, ``core.streaming``). Chunks are
         assembled across shard boundaries, copying only the sliced rows out
         of the mmap. With ``pad=True`` every chunk has exactly
         ``chunk_rows`` rows, the tail zero-filled (inert, DESIGN.md §3).

@@ -52,6 +52,18 @@ def pack_bits_device(dense: jax.Array, num_items: int | None = None) -> jax.Arra
     return (d.reshape(r, words, 32) << shifts).sum(axis=2, dtype=jnp.uint32)
 
 
+@functools.partial(jax.jit, static_argnames=("num_items",))
+def unpack_bits_device(packed: jax.Array, num_items: int) -> jax.Array:
+    """Device-side packed (R, W) uint32 -> dense {0,1} int8 (R, num_items).
+
+    Little-endian bits per word — the jnp twin of ``core.itemsets.unpack_bits``.
+    """
+    r, words = packed.shape
+    shifts = jnp.arange(32, dtype=jnp.uint32)
+    dense = (packed[:, :, None] >> shifts) & 1
+    return dense.reshape(r, words * 32)[:, :num_items].astype(jnp.int8)
+
+
 def support_count(
     t_dense,
     c_dense,

@@ -26,15 +26,17 @@ The leaves of host work are :class:`~repro.obs.trace.LeafSpan`s
                                           mmap: slice, concatenate, pad (once
                                           more per pass, for the read that
                                           finds the store's end)
-- ``chunk_unpack``    (``repro.stream``) — packed words to dense int8
-                                          (dense representation only)
+- ``chunk_unpack``    (``repro.stream``) — empty: no unpack is left on the
+                                          host (the dense count step unpacks
+                                          the words on the device); kept while
+                                          the benchmark reads it
 - ``chunk_place``     (``repro.stream``) — the chunk's host→device transfer,
                                           as far as the host thread waits
 - ``count_dispatch``  (``repro.stream``) — dispatch of the jit'd accumulate
                                           step (asynchronous: not kernel time)
 - ``host_sync``       (``repro.level``)  — final device→host sync of a pass
 
-The read, unpack and place run on the chunk producer's thread. Two more
+The read, (empty) unpack and place run on the chunk producer's thread. Two more
 phases are timed by the caller and kept off the profiler:
 
 - ``prefetch_stall``  — the fold blocked on the chunk producer: a thread
@@ -185,9 +187,13 @@ class MiningObs:
         else:
             self.tracer.add_span(self._level_span, f"mine.{phase}", t0, t1)
 
-    def on_chunk(self, rows: int) -> None:
+    def on_chunk(self, rows: int, nbytes: int) -> None:
+        """One chunk folded: its rows, and the bytes placed for them (the
+        store's packed words, ``4·ceil(I/32)`` a row, for either
+        representation)."""
         self.registry.counter("mine_chunks_streamed").inc()
         self.registry.counter("mine_rows_streamed").inc(rows)
+        self.registry.counter("mine_bytes_streamed").inc(nbytes)
         if self.progress is not None:
             self.progress.on_rows(rows)
 

@@ -61,6 +61,21 @@ def test_pack_bits_device_matches_host():
         np.testing.assert_array_equal(unpack_bits(pack_bits(dense), i), dense)
 
 
+@pytest.mark.parametrize("num_items", [1, 31, 32, 33, 45, 1000])
+def test_unpack_bits_device_matches_host(num_items):
+    """The device unpack of the store's uint32 words is the host's
+    ``unpack_bits``, with an all-zero padding row unpacking to an all-zero
+    row."""
+    rng = np.random.default_rng(num_items)
+    dense = (rng.random((9, num_items)) < 0.4).astype(np.int8)
+    dense[4] = 0
+    words = pack_bits(dense)
+    got = np.asarray(ops.unpack_bits_device(jnp.asarray(words), num_items))
+    assert got.dtype == np.int8 and got.shape == dense.shape
+    np.testing.assert_array_equal(got, unpack_bits(words, num_items))
+    np.testing.assert_array_equal(got, dense)
+
+
 # ---------------------------------------------------- dispatch parity --------
 RANDOM_SHAPES = [
     (8, 16, 4),       # tiny

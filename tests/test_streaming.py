@@ -11,7 +11,9 @@ import numpy as np
 import pytest
 
 from repro.core import streaming
-from repro.core.apriori import AprioriConfig, mine, _count_level, make_count_step, place_db
+from repro.core.apriori import (AprioriConfig, _count_level, _place_candidates, make_count_step,
+                                mine, place_db)
+from repro.core.itemsets import pack_bits
 from repro.core.son import mine_son
 from repro.data import store as st
 from repro.data.synthetic import QuestConfig, gen_transactions
@@ -82,24 +84,66 @@ def test_multi_pass_candidate_split(tmp_path):
 @pytest.mark.parametrize("rep", ["dense", "packed"])
 @pytest.mark.parametrize("where", [{}, {"start_chunk": 2}, {"shards": (1, 3)}])
 def test_observed_producer_yields_the_same_chunks(tmp_path, rep, where, observed):
-    """The producer reads the store's packed words and unpacks them for the
-    dense step as a step of its own: with or without a MiningObs its chunks
-    are byte-identical to the store's, unpacked, for every representation,
-    cursor and shard range."""
+    """The producer ships the store's padded packed words as read, for every
+    representation, cursor and shard range, with or without a MiningObs:
+    each chunk is byte-identical to the store's padded chunk, and the
+    representation's count step counts it as the unpacked rows."""
     from repro.core.itemsets import unpack_bits
     from repro.obs import MetricsRegistry, MiningObs
 
     t, _, _ = random_problem(130, 45, 4, seed=4)
     s = _store_from_dense(t, tmp_path / "db", shard_rows=40)
-    cfg = AprioriConfig(count_impl="jnp", representation=rep)
-    want = [c if rep == "packed" else unpack_bits(c, 45)
-            for c, _ in s.iter_chunks(17, pad=True, **where)]
+    want = [c for c, _ in s.iter_chunks(17, pad=True, **where)]
+    cfg = AprioriConfig(count_impl="jnp", representation=rep, candidate_pad=64)
     obs = MiningObs(registry=MetricsRegistry()) if observed else None
-    got = list(streaming._chunks(s, 17, cfg, obs, **where))
+    got = list(streaming._chunks(s, 17, obs, **where))
     assert len(got) == len(want) > 0
     for g, w in zip(got, want):
-        assert g.dtype == w.dtype and g.shape == w.shape
+        assert g.dtype == w.dtype == np.uint32 and g.shape == w.shape == (17, 2)
         assert g.tobytes() == w.tobytes()
+    singles = np.arange(45, dtype=np.int32)[:, None]
+    c_dev, len_dev = _place_candidates(singles, 64, 45, cfg, None)
+    step = make_count_step(None, cfg)
+    for g, w in zip(got, want):
+        counts = np.asarray(step(jnp.asarray(g), c_dev, len_dev))[:45]
+        np.testing.assert_array_equal(counts, unpack_bits(w, 45).sum(axis=0))
+
+
+def _pair_problem(num_items, impl):
+    """64 dense rows (the last five zero padding), their pair candidates
+    placed for the dense step, and that step."""
+    t, _, _ = random_problem(64, num_items, 4, seed=num_items)
+    t[-5:] = 0
+    rng = np.random.default_rng(num_items)
+    cands = np.unique(np.sort([rng.choice(num_items, size=2, replace=False) for _ in range(60)],
+                              axis=1), axis=0).astype(np.int32)
+    cfg = AprioriConfig(count_impl=impl, candidate_pad=128)
+    c_dev, len_dev = _place_candidates(cands, 128, num_items, cfg, None)
+    want = [int(np.all(t[:, c] == 1, axis=1).sum()) for c in cands]
+    return t, c_dev, len_dev, make_count_step(None, cfg), want
+
+
+@pytest.mark.parametrize("impl", ["jnp", "pallas_interpret"])
+@pytest.mark.parametrize("num_items", [45, 96])
+def test_dense_count_step_counts_words_as_rows(impl, num_items):
+    """The dense step given the store's packed uint32 words counts exactly
+    what it counts from the unpacked int8 rows, zero padding rows included."""
+    t, c_dev, len_dev, step, want = _pair_problem(num_items, impl)
+    from_rows = np.asarray(step(jnp.asarray(t), c_dev, len_dev))
+    np.testing.assert_array_equal(
+        np.asarray(step(jnp.asarray(pack_bits(t)), c_dev, len_dev)), from_rows)
+    np.testing.assert_array_equal(from_rows[: len(want)], want)
+
+
+@pytest.mark.parametrize("impl", ["jnp", "pallas_interpret"])
+@pytest.mark.parametrize("num_items", [45, 96])
+def test_dense_count_step_counts_uint8_rows_as_rows(impl, num_items):
+    """Only uint32 means packed words: a dense {0,1} uint8 (N, I) matrix is
+    rows, and counts as the same rows in int8 do."""
+    t, c_dev, len_dev, step, want = _pair_problem(num_items, impl)
+    from_uint8 = np.asarray(step(jnp.asarray(t.astype(np.uint8)), c_dev, len_dev))
+    np.testing.assert_array_equal(from_uint8, np.asarray(step(jnp.asarray(t), c_dev, len_dev)))
+    np.testing.assert_array_equal(from_uint8[: len(want)], want)
 
 
 # --------------------------------------------------- end-to-end equality -----

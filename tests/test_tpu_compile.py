@@ -118,6 +118,25 @@ def test_mesh_count_step_compiles(mesh, representation):
     assert "tpu_custom_call" in text and "all-reduce" in text
 
 
+def test_dense_count_step_compiles_from_words(one_chip, mesh):
+    """The streamed dense count step at the benchmark's chunk: 8,192 rows of
+    the store's uint32 words for 1,000 items, unpacked on the chip ahead of
+    the kernel, on one chip and per shard of the 2x2 mesh."""
+    n, items, k, width, dtype = 8192, 1000, 65536, 32, jnp.uint32
+    cfg = AprioriConfig(count_impl="pallas")
+    text = make_count_step(None, cfg).lower(
+        _sds((n, width), dtype, one_chip), _sds((k, items), jnp.int8, one_chip),
+        _sds((k,), jnp.int32, one_chip)).compile().as_text()
+    assert "tpu_custom_call" in text and "shift-right-logical" in text
+    cfg = AprioriConfig(count_impl="pallas", data_axes=("data",), model_axis="model")
+    text = make_count_step(mesh, cfg).lower(
+        _sds((n, width), dtype, NamedSharding(mesh, P(("data",), None))),
+        _sds((k, items), jnp.int8, NamedSharding(mesh, P("model", None))),
+        _sds((k,), jnp.int32, NamedSharding(mesh, P("model"))),
+    ).compile().as_text()
+    assert "tpu_custom_call" in text and "shift-right-logical" in text and "all-reduce" in text
+
+
 def test_mesh_match_step_compiles(mesh):
     """The 2x2 serving step: baskets over 'data', rules over 'model', the
     item scores summed over the rule shards."""
