@@ -64,6 +64,7 @@ class _RegistryMetrics:
     attributes, with one lock covering every metric for atomic snapshots."""
 
     _COUNTER_FIELDS: tuple = ()
+    _SPANS: dict = {}     # seconds field -> (layer, phase) of its LeafSpan
 
     def __init__(self, registry: Optional[MetricsRegistry] = None, *, prefix: str):
         self.registry = registry if registry is not None else MetricsRegistry()
@@ -72,6 +73,8 @@ class _RegistryMetrics:
                           for f in self._COUNTER_FIELDS}
         self.latency = self.registry.register(
             LatencyHistogram(f"{prefix}_latency_seconds", lock=self.registry.lock))
+        self._seconds = {f: self.registry.gauge(f"{prefix}_{f[:-2]}_seconds")
+                         for f in self._SPANS}
 
     def __getattr__(self, name):
         counters = self.__dict__.get("_counters")
@@ -81,6 +84,10 @@ class _RegistryMetrics:
 
     def _inc(self, field: str, n: int = 1) -> None:
         self._counters[field].inc(n)
+
+    def span(self, field: str) -> LeafSpan:
+        """The leaf span that times one of the ``_SPANS`` seconds fields."""
+        return LeafSpan(*self._SPANS[field], self._seconds[field])
 
 
 class GatewayMetrics(_RegistryMetrics):
@@ -121,8 +128,7 @@ class GatewayMetrics(_RegistryMetrics):
 
     def __init__(self, registry: Optional[MetricsRegistry] = None):
         super().__init__(registry, prefix="gateway")
-        self._seconds = {f: self.registry.gauge(f"gateway_{f[:-2]}_seconds")
-                         for f in (*self._SPANS, "queue_wait_s")}
+        self._seconds["queue_wait_s"] = self.registry.gauge("gateway_queue_wait_seconds")
         self.generation_age = self.registry.register(
             GenerationAgeGauge("gateway_generation_age_seconds",
                                lock=self.registry.lock))
@@ -137,10 +143,6 @@ class GatewayMetrics(_RegistryMetrics):
 
     def record_cache(self, hit: bool) -> None:
         self._inc("cache_hits" if hit else "cache_misses")
-
-    def span(self, field: str) -> LeafSpan:
-        """The leaf span that times one of the worker's ``_SPANS`` fields."""
-        return LeafSpan(*self._SPANS[field], self._seconds[field])
 
     def record_batch(self, real_rows: int, padded_rows: int,
                      queue_wait_s: float = 0.0) -> None:
@@ -206,7 +208,18 @@ class RouterMetrics(_RegistryMetrics):
 
     Router latency is submit → terminal outcome INCLUDING failover retries
     and backoff, so it is an end-to-end client view; a replica gateway's own
-    histogram sees only the attempts that reached it."""
+    histogram sees only the attempts that reached it.
+
+    The router's host seconds, each a :class:`~repro.obs.trace.LeafSpan` on
+    the profiler's clock: ``admit_s`` (``Router.submit``, entry to return:
+    brownout check, pack, ring lookup, hand-off to a replica;
+    ``repro.router.admit``), ``complete_s`` (an attempt's answer through the
+    outer future and its callbacks, or its failover; ``repro.router.complete``),
+    ``sweep_s`` (one pass of the driver: due retries, the attempt-timeout
+    scan and its redispatches; ``repro.router.sweep``) and ``health_s`` (one
+    health-monitor tick; ``repro.router.health``). A cache hit answers inside
+    ``submit``, so its completion is timed inside the admission.
+    """
 
     _COUNTER_FIELDS = (
         "routed",             # requests accepted by the router
@@ -224,6 +237,12 @@ class RouterMetrics(_RegistryMetrics):
         "brownout_sheds",         # requests shed by alert-driven brownout (§14)
         "alert_resyncs",          # re-syncs triggered by a generation-lag alert
     )
+    _SPANS = {
+        "admit_s": ("router", "admit"),
+        "complete_s": ("router", "complete"),
+        "sweep_s": ("router", "sweep"),
+        "health_s": ("router", "health"),
+    }
 
     def __init__(self, registry: Optional[MetricsRegistry] = None):
         super().__init__(registry, prefix="router")
@@ -308,6 +327,7 @@ class RouterMetrics(_RegistryMetrics):
     def snapshot(self) -> dict:
         with self._lock:
             out = {f: self._counters[f].value for f in self._COUNTER_FIELDS}
+            out.update((f, g.value) for f, g in self._seconds.items())
             out["max_generation_lag"] = int(self._max_lag.value)
             out["current_generation_lag"] = int(self._cur_lag.value)
             out["healthy_replica_ratio"] = float(self._healthy_ratio.value)

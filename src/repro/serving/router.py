@@ -353,47 +353,48 @@ class Router:
         Raises :class:`AdmissionRejected` when the router is closed or no
         candidate replica can take the request (all dead / all saturated) —
         the load-shedding path."""
-        if self._closed:
-            self.metrics.record_shed()
-            raise AdmissionRejected("router closed")
-        level = self._brownout_level
-        if level:
-            # brownout: admit only while aggregate queue fill stays under the
-            # level's budget — overload sheds EARLY (typed reject in ~µs)
-            # instead of queueing work the burning tier cannot absorb
-            depth = cap = 0
-            for rep in self._replicas:
-                if rep.available:
-                    depth += rep.gateway.queue_depth
-                    cap += rep.gateway.queue_capacity
-            budget = 0.5 if level == 1 else 0.25
-            if cap == 0 or depth >= cap * budget:
-                self.metrics.record_brownout_shed()
-                raise AdmissionRejected(
-                    f"brownout (availability alert, level {level}): "
-                    f"aggregate queue {depth}/{cap} over the {budget:.0%} budget"
-                )
-        t0 = time.perf_counter()
-        packed = self._replicas[0].gateway._pack_one(basket)
-        k = min(self.default_top_k if top_k is None else int(top_k), self.num_items)
-        deadline = None if deadline_ms is None else t0 + max(0.0, float(deadline_ms)) / 1e3
-        task = _RouterTask(Future(), packed, k, deadline, t0,
-                           self._ring.preference(packed.tobytes()))
-        if self._tracer is not None:
-            task.span = self._tracer.root("router.request", top_k=k)
-            if task.span is not None:
-                task.span.t0 = t0   # backdate to submit entry: admit nests
-                # admission: pack + ring lookup, before the first attempt
-                self._tracer.add_span(task.span, "router.admit", t0,
-                                      time.perf_counter(),
-                                      owner=task.pref[0])
-        if not self._try_dispatch(task):
-            self.metrics.record_shed()
-            if task.span is not None:
-                task.span.end(outcome="shed")
-            raise AdmissionRejected("all replicas dead or saturated")
-        self.metrics.record_routed()
-        return task.outer
+        with self.metrics.span("admit_s"):
+            if self._closed:
+                self.metrics.record_shed()
+                raise AdmissionRejected("router closed")
+            level = self._brownout_level
+            if level:
+                # brownout: admit only while aggregate queue fill stays under the
+                # level's budget — overload sheds EARLY (typed reject in ~µs)
+                # instead of queueing work the burning tier cannot absorb
+                depth = cap = 0
+                for rep in self._replicas:
+                    if rep.available:
+                        depth += rep.gateway.queue_depth
+                        cap += rep.gateway.queue_capacity
+                budget = 0.5 if level == 1 else 0.25
+                if cap == 0 or depth >= cap * budget:
+                    self.metrics.record_brownout_shed()
+                    raise AdmissionRejected(
+                        f"brownout (availability alert, level {level}): "
+                        f"aggregate queue {depth}/{cap} over the {budget:.0%} budget"
+                    )
+            t0 = time.perf_counter()
+            packed = self._replicas[0].gateway._pack_one(basket)
+            k = min(self.default_top_k if top_k is None else int(top_k), self.num_items)
+            deadline = None if deadline_ms is None else t0 + max(0.0, float(deadline_ms)) / 1e3
+            task = _RouterTask(Future(), packed, k, deadline, t0,
+                               self._ring.preference(packed.tobytes()))
+            if self._tracer is not None:
+                task.span = self._tracer.root("router.request", top_k=k)
+                if task.span is not None:
+                    task.span.t0 = t0   # backdate to submit entry: admit nests
+                    # admission: pack + ring lookup, before the first attempt
+                    self._tracer.add_span(task.span, "router.admit", t0,
+                                          time.perf_counter(),
+                                          owner=task.pref[0])
+            if not self._try_dispatch(task):
+                self.metrics.record_shed()
+                if task.span is not None:
+                    task.span.end(outcome="shed")
+                raise AdmissionRejected("all replicas dead or saturated")
+            self.metrics.record_routed()
+            return task.outer
 
     def query(self, basket, top_k: int | None = None, timeout: float | None = 60.0,
               deadline_ms: float | None = None):
@@ -615,29 +616,30 @@ class Router:
         return False
 
     def _on_attempt_done(self, token: int, rid: int, task: _RouterTask, fut) -> None:
-        with self._lock:
-            claimed = self._inflight.pop(token, None) is not None
-        if not claimed:
-            return    # watchdog already abandoned this attempt; late answer moot
-        rep = self._replicas[rid]
-        exc = fut.exception()
-        if task.att_span is not None:
-            task.att_span.end(
-                outcome="ok" if exc is None else type(exc).__name__)
-        if exc is None:
-            rep.note_success()
-            resp = fut.result()
-            self._finish(task, result=dataclasses.replace(
-                resp, latency_s=time.perf_counter() - task.t_submit
-            ))
-        elif isinstance(exc, DeadlineExceeded):
-            # expired in the replica's queue: terminal, and not the
-            # replica's fault — no failure note
-            self._finish(task, exc=exc, deadline=True)
-        else:
-            if not isinstance(exc, AdmissionRejected):
-                rep.note_failure(self._suspect_after)
-            self._retry_or_fail(task, exc)
+        with self.metrics.span("complete_s"):
+            with self._lock:
+                claimed = self._inflight.pop(token, None) is not None
+            if not claimed:
+                return    # watchdog already abandoned this attempt; late answer moot
+            rep = self._replicas[rid]
+            exc = fut.exception()
+            if task.att_span is not None:
+                task.att_span.end(
+                    outcome="ok" if exc is None else type(exc).__name__)
+            if exc is None:
+                rep.note_success()
+                resp = fut.result()
+                self._finish(task, result=dataclasses.replace(
+                    resp, latency_s=time.perf_counter() - task.t_submit
+                ))
+            elif isinstance(exc, DeadlineExceeded):
+                # expired in the replica's queue: terminal, and not the
+                # replica's fault — no failure note
+                self._finish(task, exc=exc, deadline=True)
+            else:
+                if not isinstance(exc, AdmissionRejected):
+                    rep.note_failure(self._suspect_after)
+                self._retry_or_fail(task, exc)
 
     def _retry_or_fail(self, task: _RouterTask, exc: BaseException) -> None:
         now = time.perf_counter()
@@ -684,46 +686,52 @@ class Router:
     def _drive(self) -> None:
         """Pop due retries and time out unresponsive in-flight attempts."""
         while not self._stop_driver.wait(0.005):
-            now = time.perf_counter()
-            due: list[_RouterTask] = []
-            timed_out: list[tuple] = []
-            with self._lock:
-                while self._heap and self._heap[0][0] <= now:
-                    due.append(heapq.heappop(self._heap)[2])
-                expired = [t for t, (_, _, at) in self._inflight.items() if now >= at]
-                for t in expired:
-                    timed_out.append(self._inflight.pop(t))
-            for task in due:
-                if task.outer.done():
-                    continue
-                if task.span is not None and task.t_parked:
-                    # the failover gap: parked after a failed attempt until
-                    # redispatched to the next candidate
-                    self._tracer.add_span(task.span, "router.failover",
-                                          task.t_parked, now,
-                                          next_attempt=task.attempts + 1)
-                    task.t_parked = 0.0
-                if not self._try_dispatch(task):
-                    task.attempts += 1    # a burnt retry, not a free spin
-                    self._retry_or_fail(
-                        task, AdmissionRejected("no replica available for retry")
-                    )
-            for task, rid, _ in timed_out:
-                if task.outer.done():
-                    continue
-                if task.att_span is not None:
-                    task.att_span.end(outcome="timeout")
-                self.metrics.record_attempt_timeout()
-                self._replicas[rid].note_failure(self._suspect_after)
-                self._retry_or_fail(task, WorkerCrashed(
-                    f"replica {rid} unresponsive: attempt exceeded "
-                    f"{self._attempt_timeout * 1e3:.0f} ms"
-                ))
+            with self.metrics.span("sweep_s"):
+                self._sweep()
+
+    def _sweep(self) -> None:
+        """One pass of the driver (its wait excluded)."""
+        now = time.perf_counter()
+        due: list[_RouterTask] = []
+        timed_out: list[tuple] = []
+        with self._lock:
+            while self._heap and self._heap[0][0] <= now:
+                due.append(heapq.heappop(self._heap)[2])
+            expired = [t for t, (_, _, at) in self._inflight.items() if now >= at]
+            for t in expired:
+                timed_out.append(self._inflight.pop(t))
+        for task in due:
+            if task.outer.done():
+                continue
+            if task.span is not None and task.t_parked:
+                # the failover gap: parked after a failed attempt until
+                # redispatched to the next candidate
+                self._tracer.add_span(task.span, "router.failover",
+                                      task.t_parked, now,
+                                      next_attempt=task.attempts + 1)
+                task.t_parked = 0.0
+            if not self._try_dispatch(task):
+                task.attempts += 1    # a burnt retry, not a free spin
+                self._retry_or_fail(
+                    task, AdmissionRejected("no replica available for retry")
+                )
+        for task, rid, _ in timed_out:
+            if task.outer.done():
+                continue
+            if task.att_span is not None:
+                task.att_span.end(outcome="timeout")
+            self.metrics.record_attempt_timeout()
+            self._replicas[rid].note_failure(self._suspect_after)
+            self._retry_or_fail(task, WorkerCrashed(
+                f"replica {rid} unresponsive: attempt exceeded "
+                f"{self._attempt_timeout * 1e3:.0f} ms"
+            ))
 
     # ----------------------------------------------------- health monitor --
     def _monitor_loop(self) -> None:
         while not self._stop_monitor.wait(self._monitor_interval):
-            self._health_tick()
+            with self.metrics.span("health_s"):
+                self._health_tick()
 
     def _health_tick(self) -> None:
         now = time.perf_counter()

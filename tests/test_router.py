@@ -542,3 +542,77 @@ def test_router_generation_age_resets_on_coordinated_swap(rulebooks):
         assert r.metrics.generation_age.value < pre_swap
         assert r.metrics.registry.raw_snapshot()[
             "router_generation_age_seconds"] < pre_swap
+
+
+# ------------------------------------------------- spans on the profiler's clock --
+SPAN_SECONDS = ("admit_s", "complete_s", "sweep_s", "health_s")
+
+
+def test_admit_and_complete_seconds_grow_with_answered_requests(rulebooks):
+    rb0, _ = rulebooks
+    with Router(rb0, 2, warmup=False, max_wait_ms=0.0, cache_capacity=0) as r:
+        before = r.stats()
+        assert all(before[f] >= 0.0 for f in SPAN_SECONDS)
+        for basket in fresh_baskets(12, seed=31):
+            check_response(r.query(basket, timeout=30), rb0, basket, r.default_top_k)
+        after = r.stats()
+        assert after["completed"] - before["completed"] == 12
+        assert after["admit_s"] > before["admit_s"]
+        assert after["complete_s"] > before["complete_s"]
+        assert r.metrics.registry.raw_snapshot()["router_admit_seconds"] == after["admit_s"]
+
+
+def test_admit_and_complete_seconds_grow_with_failed_over_requests(rulebooks):
+    """A killed worker's attempt fails over: its completion (the failed
+    attempt, then the answer from the next replica) is timed too."""
+    rb0, _ = rulebooks
+    with Router(rb0, 2, warmup=False, max_wait_ms=0.0, cache_capacity=0,
+                attempt_timeout_s=0.5,
+                fault=FaultConfig(max_retries=3, backoff_s=0.01)) as r:
+        r.query(fresh_baskets(1, seed=9)[0], timeout=30)
+        before = r.stats()
+        r.fault_injection.kill_replica(0)
+        r.fault_injection.kill_replica(1)
+        baskets = fresh_baskets(16, seed=32)
+        futs = [r.submit(b) for b in baskets]
+        for b, f in zip(baskets, futs):
+            check_response(f.result(timeout=30), rb0, b, r.default_top_k)
+        after = r.stats()
+        assert after["failovers"] - before["failovers"] >= 1
+        assert after["admit_s"] > before["admit_s"]
+        assert after["complete_s"] > before["complete_s"]
+
+
+def test_sweep_and_health_seconds_grow_while_the_router_idles(rulebooks):
+    rb0, _ = rulebooks
+    with Router(rb0, 2, warmup=False, monitor_interval_s=0.01) as r:
+        before = r.stats()
+        time.sleep(0.3)
+        after = r.stats()
+        assert after["sweep_s"] > before["sweep_s"]
+        assert after["health_s"] > before["health_s"]
+        assert after["routed"] == before["routed"] == 0
+
+
+def test_router_spans_are_host_events_in_a_profiler_trace(rulebooks, tmp_path):
+    import glob
+
+    import jax
+
+    rb0, _ = rulebooks
+    with Router(rb0, 2, warmup=False, max_wait_ms=0.0, monitor_interval_s=0.01) as r:
+        r.query(fresh_baskets(1, seed=9)[0], timeout=30)    # compiles outside the trace
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            for basket in fresh_baskets(6, seed=33):
+                r.query(basket, timeout=30)
+            time.sleep(0.05)
+        finally:
+            jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {e.name
+             for plane in jax.profiler.ProfileData.from_file(path).planes
+             if plane.name == "/host:CPU"
+             for line in plane.lines for e in line.events}
+    assert {"repro.router.admit", "repro.router.complete", "repro.router.sweep",
+            "repro.router.health"} <= names
